@@ -1,9 +1,11 @@
 """Subprocess worker for the PF scaling benchmarks.
 
-Runs one (DRA × device-count × particle-count) configuration on a CPU
-device mesh and prints a JSON result line.  Invoked by the fig5/7/8
-harnesses with XLA_FLAGS=--xla_force_host_platform_device_count=<P> so the
-parent process (and every other benchmark) keeps seeing one device.
+Runs one (DRA × device-count × particle-count) configuration on a
+simulated CPU device mesh and prints a JSON result line (on an
+accelerator it runs on the first ``--devices`` chips, and fails if fewer
+exist).  Invoked by the fig5/7/8 harnesses with
+XLA_FLAGS=--xla_force_host_platform_device_count=<P> so the parent
+process (and every other benchmark) keeps seeing one device.
 """
 import argparse
 import json

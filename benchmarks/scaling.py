@@ -23,6 +23,18 @@ def run_worker(devices: int, dra: str, particles: int, *, scheduler="lgs",
                exchange_ratio=0.10, frames=10, img=128, repeats=2,
                domain=False, k_cap=0, butterfly_cap=32, warmup=None,
                timeout=1200) -> dict:
+    """Run one ``pf_worker.py`` configuration in a child process on
+    simulated CPU devices; returns its JSON result line.
+
+    CPU only: on an accelerator this process holds the chip, so a child
+    that needs it would fail or hang — refuse instead.
+    """
+    import jax
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            f"run_worker simulates devices in a child process and runs on "
+            f"the CPU only; this process holds the "
+            f"{jax.default_backend()} device the child would need")
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     env.pop("XLA_FLAGS", None)

@@ -1,13 +1,16 @@
 """Quickstart: track a fluorescent spot with the PPF library in ~20 lines,
 then track a whole bank of targets with one compiled program, then run
 the same filter domain-decomposed — each shard owning one tile of the
-frame — on a simulated 4-device mesh.
+frame — on a 4-device mesh (simulated on the CPU; on an accelerator,
+as many chips as the host has, up to 4).
 
     PYTHONPATH=src python examples/quickstart.py
 """
 from repro.core import runtime
 
-runtime.simulate_host_devices(4)     # before any device use (DESIGN.md §6)
+# before any device use (DESIGN.md §6)
+N_DEVICES = min(runtime.simulate_host_devices(4), 4)
+runtime.enable_compile_cache()
 
 import jax                           # noqa: E402
 import jax.numpy as jnp              # noqa: E402
@@ -63,10 +66,11 @@ def main() -> None:
     # are tile-sharded halo slabs, particles migrate to their tile owners
     # after every dynamics step, and the trajectories are EXACTLY those of
     # the replicated-frame filter — only the frame memory placement changes.
-    spec = make_domain_spec(cfg, tiles=4)          # halo = cfg.patch_radius
+    spec = make_domain_spec(cfg, tiles=N_DEVICES)  # halo = cfg.patch_radius
     dpf = ParallelParticleFilter(
         model=model, sir=SIRConfig(n_particles=16384, ess_frac=0.5),
-        dra=DRAConfig(kind="rna"), mesh=make_host_mesh(4), domain=spec)
+        dra=DRAConfig(kind="rna"), mesh=make_host_mesh(N_DEVICES),
+        domain=spec)
     dres = dpf.run(jax.random.key(1), movie.frames)
     drmse = tracking_rmse(dres.estimates, movie.trajectories[:, 0], warmup=10)
     print(f"domain-decomposed on a {spec.grid} tile grid: "

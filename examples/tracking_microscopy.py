@@ -9,8 +9,8 @@ Reproduces the experimental pipeline at container scale:
     PYTHONPATH=src python examples/tracking_microscopy.py \
         --devices 8 --dra rpa --scheduler lgs --particles 262144
 
-Multi-device runs re-exec themselves with XLA_FLAGS so the parent Python
-session is untouched.
+``--devices N`` runs on N simulated host devices on the CPU, and on the
+first N chips of an accelerator host (it fails if fewer exist).
 """
 import argparse
 import time
@@ -27,12 +27,12 @@ def main() -> None:
     ap.add_argument("--particles", type=int, default=262144)
     ap.add_argument("--frames", type=int, default=50)
     ap.add_argument("--img", type=int, default=512)
-    ap.add_argument("--_respawned", action="store_true")
     args = ap.parse_args()
 
-    if args.devices > 1 and not args._respawned:
-        from repro.core import runtime
-        runtime.respawn_with_host_devices(args.devices, script=__file__)
+    from repro.core import runtime
+    if args.devices > 1:
+        runtime.simulate_host_devices(args.devices)
+    runtime.enable_compile_cache()
 
     import jax
     from repro.core import SIRConfig, ParallelParticleFilter

@@ -66,8 +66,9 @@ class DRAConfig:
     resampler: str = "systematic"
     ess_frac: float = 0.5            # N_threshold = ess_frac * N (Alg. 1)
     # local-resampling backend: "pallas" = fused CDF+bisection kernel
-    # (interpret mode off-TPU), "jnp" = pure-XLA comb, "auto" = pallas on
-    # TPU, jnp elsewhere.  Only the systematic scheme has a kernel.
+    # (interpret mode; it does not lower for TPU, so it raises there),
+    # "jnp" = pure-XLA comb, "auto" = the jnp comb on every platform.
+    # Only the systematic scheme has a kernel.
     resample_backend: str = "auto"
     # RNA / ARNA
     exchange_ratio: float = 0.10     # paper's 10%–50%
@@ -106,15 +107,13 @@ def use_pallas_resample(cfg: DRAConfig, n_out) -> bool:
     step, where ``n_out`` is the python-int slot count C); RPA's traced
     per-shard allocation stays on the jnp comb (DESIGN.md §2.1).
     """
-    if cfg.resample_backend == "jnp" or cfg.resampler != "systematic":
-        return False
-    if not isinstance(n_out, int):
-        return False
-    if not resample_kernel.kernel_applicable(n_out):
-        return False
-    if cfg.resample_backend == "pallas":
-        return True
-    return jax.default_backend() == "tpu"       # auto
+    # "auto" never picks the kernel: Mosaic refuses it on TPU
+    # (resample_kernel.CUMSUM_REFUSED) and off the chip it only runs in
+    # interpret mode
+    return (cfg.resample_backend == "pallas"
+            and cfg.resampler == "systematic"
+            and isinstance(n_out, int)
+            and resample_kernel.kernel_applicable(n_out))
 
 
 def _per_particle_bytes(state: Any) -> int:

@@ -1,20 +1,18 @@
-"""Version-portable distributed runtime facade (DESIGN.md §2, §6).
+"""Distributed runtime facade (DESIGN.md §2, §6).
 
 Every SPMD primitive the PPF library touches — ``shard_map``, mesh
 construction, the collectives the DRAs are built from (``psum``,
-``all_gather``, ``ppermute``, ``all_to_all``, ...) and the simulated
-host-device harness — goes through this module.  JAX has moved these
-entry points repeatedly (``jax.experimental.shard_map.shard_map`` →
-``jax.shard_map``; ``check_rep`` → ``check_vma``; ``jax.make_mesh``
-growing ``axis_types``; ``jax.lax.axis_size`` appearing), so call sites
-importing them directly rot with every upgrade.  The facade resolves the
-installed API once at import time; nothing else in ``src/`` or ``tests/``
-may spell a ``jax.shard_map``-style path directly.
+``all_gather``, ``ppermute``, ``all_to_all``, ...), the simulated
+host-device harness and the persistent compilation cache — goes through
+this module, so nothing else in ``src/`` or ``tests/`` spells a
+``jax.shard_map``-style path directly.  It targets the installed JAX
+(``pyproject.toml`` pins the floor) and carries no branches for other
+versions.
 """
 from __future__ import annotations
 
 import os
-import sys
+import pathlib
 from typing import Any, Callable, Sequence
 
 import jax
@@ -27,8 +25,8 @@ __all__ = [
     "psum", "pmean", "pmax", "pmin", "psum_scatter",
     "all_gather", "ppermute", "all_to_all", "axis_index", "axis_size",
     "butterfly_schedule", "grouped_ppermute", "tree_bytes",
-    "simulate_host_devices", "respawn_with_host_devices",
-    "host_device_env", "HOST_DEVICE_FLAG",
+    "simulate_host_devices", "host_device_env", "HOST_DEVICE_FLAG",
+    "enable_compile_cache",
 ]
 
 
@@ -36,28 +34,16 @@ __all__ = [
 # shard_map (the one SPMD entry point)
 # ---------------------------------------------------------------------------
 
-def _resolve_shard_map() -> tuple[Callable, str]:
-    fn = getattr(jax, "shard_map", None)        # public API, JAX >= 0.6
-    if fn is not None:
-        return fn, "check_vma"
-    from jax.experimental import shard_map as _sm   # JAX 0.4.x / 0.5.x
-    return _sm.shard_map, "check_rep"
-
-
-_SHARD_MAP, _CHECK_KW = _resolve_shard_map()
-
-
 def shard_map(f: Callable, mesh, *, in_specs, out_specs,
               check_replication: bool = False) -> Callable:
     """Map ``f`` as an SPMD program over ``mesh``.
 
-    ``check_replication`` maps onto whichever replication-checking kwarg
-    the installed JAX spells (``check_rep`` before 0.6, ``check_vma``
-    after); the library always runs with it off because the DRAs splice
-    per-shard buffers whose replication the checker cannot prove.
+    ``check_replication`` is ``jax.shard_map``'s ``check_vma``; the
+    library always runs with it off because the DRAs splice per-shard
+    buffers whose replication the checker cannot prove.
     """
-    return _SHARD_MAP(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **{_CHECK_KW: check_replication})
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_replication)
 
 
 # ---------------------------------------------------------------------------
@@ -75,18 +61,9 @@ all_to_all = jax.lax.all_to_all
 axis_index = jax.lax.axis_index
 
 
-def axis_size(axis_name: str) -> int:
-    """Static size of a named mesh axis, from inside a shard_map body.
-
-    ``jax.lax.axis_size`` only exists in newer JAX; on older versions
-    ``psum`` of the python literal 1 constant-folds at trace time to the
-    axis size, so the result is a plain ``int`` either way (callers use
-    it in ``range()`` and shape arithmetic).
-    """
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return jax.lax.psum(1, axis_name)
+# static python int inside a shard_map body (call sites use it in
+# ``range()`` and shape arithmetic)
+axis_size = jax.lax.axis_size
 
 
 # ---------------------------------------------------------------------------
@@ -149,28 +126,27 @@ def jnp_dtype_size(dtype) -> int:
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str],
               *, devices: Sequence[Any] | None = None):
-    """``jax.make_mesh`` across JAX versions.
-
-    Newer JAX wants ``axis_types`` to pin the axes to Auto sharding mode;
-    older JAX rejects the kwarg (everything is Auto).  Oldest JAX has no
-    ``jax.make_mesh`` at all — fall back to reshaping the device list.
-    """
-    maker = getattr(jax, "make_mesh", None)
-    if maker is not None:
-        axis_type = getattr(jax.sharding, "AxisType", None)
-        if axis_type is not None:
-            return maker(axis_shapes, axis_names,
-                         axis_types=(axis_type.Auto,) * len(axis_names),
+    """``jax.make_mesh`` with every axis in Auto sharding mode."""
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=(jax.sharding.AxisType.Auto,)
+                         * len(axis_names),
                          devices=devices)
-        return maker(axis_shapes, axis_names, devices=devices)
-    devs = np.asarray(devices if devices is not None else jax.devices())
-    return jax.sharding.Mesh(devs.reshape(tuple(axis_shapes)), axis_names)
 
 
 def host_mesh(n: int | None = None, axis: str = "data"):
-    """1-D mesh over the first ``n`` available devices (PF scaling runs)."""
-    devs = jax.devices()[: (n or len(jax.devices()))]
-    return jax.sharding.Mesh(np.array(devs), (axis,))
+    """1-D mesh over the first ``n`` devices (default: all of them).
+
+    Raises when fewer than ``n`` devices exist: a silently smaller mesh
+    would change the experiment (a 1-device mesh even takes the
+    single-device filter path, so the DRA would never run).
+    """
+    devs = jax.devices()
+    n = n or len(devs)
+    if len(devs) < n:
+        raise RuntimeError(
+            f"asked for a {n}-device mesh but only {len(devs)} "
+            f"{devs[0].platform} device(s) exist")
+    return jax.sharding.Mesh(np.array(devs[:n]), (axis,))
 
 
 # ---------------------------------------------------------------------------
@@ -194,38 +170,55 @@ def host_device_env(n: int, env: dict | None = None) -> dict:
     return env
 
 
-def simulate_host_devices(n: int, *, strict: bool = True) -> int:
-    """Expose ``n`` simulated CPU devices to this process.
+def simulate_host_devices(n: int) -> int:
+    """Give this process ``n`` devices to build meshes on.
 
-    Must run before JAX initialises its backend (importing ``jax`` is
-    fine; creating an array or listing devices is not).  Returns the
-    device count actually visible; with ``strict`` raises if the backend
-    was already up with fewer devices — in that case use
-    ``respawn_with_host_devices`` or set XLA_FLAGS in the launcher.
+    On the CPU backend this exposes ``n`` simulated host devices, so it
+    must run before JAX initialises its backend (importing ``jax`` is
+    fine; creating an array or listing devices is not) and raises if the
+    backend is already up with fewer.  On an accelerator the flag is
+    inert and the real devices are used: the return value is the number
+    visible, which may be fewer than ``n`` — ``host_mesh(n)`` raises in
+    that case.
     """
     os.environ["XLA_FLAGS"] = host_device_env(n)["XLA_FLAGS"]
     got = jax.device_count()
-    if strict and got < n:
+    if jax.default_backend() == "cpu" and got < n:
         raise RuntimeError(
             f"asked for {n} simulated host devices but the JAX backend is "
             f"already initialised with {got}; call simulate_host_devices "
-            f"before any device use, or respawn_with_host_devices")
+            f"before any device use, or launch with XLA_FLAGS set")
     return got
 
 
-def respawn_with_host_devices(n: int, module: str | None = None, *,
-                              script: str | None = None,
-                              sentinel: str = "--_respawned") -> None:
-    """Re-exec this CLI with ``n`` simulated devices.
+# ---------------------------------------------------------------------------
+# Persistent compilation cache
+# ---------------------------------------------------------------------------
 
-    Pass ``module`` for ``python -m module`` entry points or ``script``
-    for path-invoked ones.  For CLIs that parse args before touching JAX.
-    The sentinel flag marks the respawned process so it doesn't recurse;
-    the caller is responsible for accepting (and ignoring) it.  Never
-    returns.
+# fixed, so its path (part of every cache key) is the same on every run
+# from this checkout; listed in .gitignore
+DEFAULT_CACHE_DIR = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_compile_cache() -> str | None:
+    """Turn on JAX's persistent compilation cache for an accelerator;
+    returns its directory (``None`` on the CPU backend).
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is honoured as JAX reads it
+    and no other directory is set here; otherwise the cache lives in
+    ``.jax_cache/`` at the checkout root.  On the CPU nothing is set:
+    XLA:CPU warns that a cached executable may not match the host's
+    features (SIGILL), and CPU compiles are cheap.  Entry points call
+    this after ``simulate_host_devices`` and before their first compile;
+    importing the library never does.
     """
-    assert (module is None) != (script is None), "pass module OR script"
-    entry = [script] if script is not None else ["-m", module]
-    os.execve(sys.executable,
-              [sys.executable] + entry + sys.argv[1:] + [sentinel],
-              host_device_env(n))
+    if jax.default_backend() == "cpu":
+        return None
+    directory = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not directory:
+        directory = str(DEFAULT_CACHE_DIR)
+        jax.config.update("jax_compilation_cache_dir", directory)
+    # cache every program, not only the slow-to-compile ones
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    return directory

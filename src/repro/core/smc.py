@@ -112,14 +112,15 @@ class SIRConfig:
         resample as separate ops (the historical, golden-pinned path);
         ``"fused"`` runs the whole weight phase through
         ``repro.kernels.sir_fused`` — one normalization shared by every
-        statistic, ancestors without the counts round-trip, and the
-        Pallas megakernel on TPU (DESIGN.md §13).  Configs a fused step
+        statistic, ancestors without the counts round-trip, as one
+        XLA program on every platform (DESIGN.md §13; its Pallas
+        megakernel does not lower for TPU yet).  Configs a fused step
         cannot honor (a comb-only resampler, the per-shard DRA step,
         ancestry recording, an ``estimate_state`` model hook) fall back
         to the composed path automatically.
       fused_backend: optional override of the fused execution backend
-        (``"pallas"`` / ``"interpret"`` / ``"xla"``); ``None`` resolves
-        from the platform like the rest of the kernel layer.
+        (``"interpret"`` / ``"xla"``; ``"pallas"`` raises, as does
+        ``"interpret"`` on a TPU); ``None`` is ``"xla"``.
       record_ancestry: emit the per-step ancestor indices in
         ``StepOutput.ancestors`` plus the genealogy diagnostics
         (``diag["emission"]`` — the model's per-particle emission before

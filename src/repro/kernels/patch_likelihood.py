@@ -17,6 +17,10 @@ unrolled accumulation in vector registers.
 
 The matched-filter form  (ΣZ·I − ½ΣI²)/σ_ξ²  and the paper's Eq. 4 form
 −Σ(Z−I)²/2σ_ξ²  are both supported (see ``repro.models.tracking``).
+
+Mosaic does not lower this kernel yet: the per-offset ``img[py, px]``
+gather fails in its gather lowering rule (``repro.kernels.ops``), so it
+runs in interpret mode off the chip only.
 """
 from __future__ import annotations
 

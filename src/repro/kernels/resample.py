@@ -8,10 +8,12 @@ parallel (paper §II) — it needs the global weight CDF.  The kernel fuses:
   2. the stratified-comb binary search, blocked over output positions.
 
 The CDF lives in VMEM across all (sequential) grid steps, so the search
-pass never touches HBM for it.  Capacity: N f32 ≤ ~2M fits the 16 MB VMEM
-of a v5e core alongside blocks; per-shard ensembles in the distributed
-resamplers are far below that (global N scales with the mesh, per-shard N
-does not — that is the point of the PPF library).
+pass never touches HBM for it.  Mosaic does not lower this kernel yet
+(``CUMSUM_REFUSED`` below), so it runs in interpret mode off the chip
+only.  Capacity: N f32 ≤ ~2M fits the 16 MB VMEM of a v5e core alongside
+blocks; per-shard ensembles in the distributed resamplers are far below
+that (global N scales with the mesh, per-shard N does not — that is the
+point of the PPF library).
 
 Binary search is expressed as a fixed ``ceil(log2(N))``-step vectorized
 bisection (Pallas has no searchsorted primitive on TPU).
@@ -111,15 +113,40 @@ def kernel_applicable(n_out: int) -> bool:
     return pick_block(n_out) >= 8
 
 
+# What Mosaic (jax 0.9.0, libtpu 0.0.34) says when asked to compile these
+# kernels for a TPU v5e.  Every kernel of this module, of
+# ``repro.kernels.sir_fused`` and of ``repro.kernels.patch_likelihood`` is
+# refused, although each passes its interpret-mode tests; so no TPU path
+# selects them, and they run in interpret mode off the chip only.
+CUMSUM_REFUSED = ("NotImplementedError: Unimplemented primitive in Pallas "
+                  "TPU lowering for KernelType.TC: cumsum")
+GATHER_REFUSED = "NotImplementedError: Only 2D gather is supported"
+
+
+def interpret_only(kernel: str, refusal: str, backend: str = "interpret"
+                   ) -> bool:
+    """``interpret=`` for a kernel Mosaic refuses: always ``True``.
+
+    Raises instead where the kernel would have to run compiled — an
+    explicit ``backend="pallas"`` request, or any call on a TPU, where
+    interpret mode would quietly emulate the kernel on the chip.
+    """
+    if backend == "pallas" or jax.default_backend() == "tpu":
+        raise NotImplementedError(
+            f"the Pallas {kernel} kernel does not lower for TPU "
+            f"({refusal}); use the XLA path")
+    return True
+
+
 def systematic_ancestors_auto(log_weights: Array, u: Array, *,
                               n_out: int | None = None) -> Array:
-    """Kernel entry point with backend-appropriate defaults: compiled on
-    TPU, interpret mode elsewhere (CPU CI, the simulated-device harness),
-    block size picked to divide ``n_out``."""
+    """Kernel entry point in interpret mode (CPU CI, the simulated-device
+    harness), block size picked to divide ``n_out``; raises on a TPU
+    (``CUMSUM_REFUSED``)."""
     n_out = n_out or log_weights.shape[0]
     return systematic_ancestors_kernel(
         log_weights, u, n_out=n_out, block=pick_block(n_out),
-        interpret=jax.default_backend() != "tpu")
+        interpret=interpret_only("systematic resampling", CUMSUM_REFUSED))
 
 
 # ---------------------------------------------------------------------------

@@ -20,17 +20,21 @@ phase that normalizes once and shares the result (DESIGN.md §13):
 
 Three backends, same contract as the rest of the kernel layer:
 
-* ``xla``       — the jnp reference below under plain XLA: the fast
-  path on CPU (BENCH_kernels.json records the fused-vs-composed ratio);
-* ``pallas``    — the TPU megakernel: log-weights, CDF, and the moment
-  accumulators live in VMEM across the (sequential) grid, so the weight
-  phase reads the state exactly once from HBM and the weight vector
-  never makes an HBM round-trip between ops;
+* ``xla``       — the jnp reference below under plain XLA: the path on
+  every platform (BENCH_kernels.json records the fused-vs-composed
+  ratio on CPU);
+* ``pallas``    — the megakernel compiled for TPU: log-weights, CDF, and
+  the moment accumulators live in VMEM across the (sequential) grid, so
+  the weight phase reads the state exactly once from HBM and the weight
+  vector never makes an HBM round-trip between ops.  Mosaic does not
+  lower it yet (``resample.CUMSUM_REFUSED``; the chain kernels hit
+  ``resample.GATHER_REFUSED``), so this backend raises;
 * ``interpret`` — the Pallas kernel emulated on CPU (correctness CI).
 
-VMEM capacity: two N-f32 scratch vectors (shifted log-weights + CDF)
-plus an N×D state block stream — N ≤ ~1.5M f32 fits a v5e core's 16 MB
-alongside blocks, same envelope as ``repro.kernels.resample``.
+VMEM capacity, for when it lowers: two N-f32 scratch vectors (shifted
+log-weights + CDF) plus an N×D state block stream — N ≤ ~1.5M f32 fits a
+v5e core's 16 MB alongside blocks, same envelope as
+``repro.kernels.resample``.
 """
 from __future__ import annotations
 
@@ -300,9 +304,9 @@ def state_matrix(state: Any) -> tuple[Array, Any]:
 # ---------------------------------------------------------------------------
 
 def default_backend() -> str:
-    """``pallas`` on TPU, the jnp reference under plain XLA elsewhere —
-    same resolution rule as ``repro.kernels.ops``."""
-    return "pallas" if jax.default_backend() == "tpu" else "xla"
+    """``xla`` on every platform: Mosaic refuses the megakernel on TPU
+    (``resample.CUMSUM_REFUSED``), and interpret mode is for tests."""
+    return "xla"
 
 
 def fused_weight_step(log_weights: Array, log_lik: Array, state: Any,
@@ -311,7 +315,8 @@ def fused_weight_step(log_weights: Array, log_lik: Array, state: Any,
                       backend: str | None = None) -> FusedDecision:
     """Run the fused weight phase on the best backend available.
 
-    The Pallas path additionally requires a block-divisible N
+    The Pallas path (interpret mode only: it raises for ``"pallas"`` and
+    on a TPU) additionally requires a block-divisible N
     (``resample.pick_block``) and a flattenable float state; anything
     else silently takes the XLA reference, so callers never branch on
     platform (DESIGN.md §13.1).
@@ -322,7 +327,10 @@ def fused_weight_step(log_weights: Array, log_lik: Array, state: Any,
         return fused_weight_step_ref(log_weights, log_lik, state, key,
                                      resampler=resampler, ess_frac=ess_frac,
                                      always=always)
-    interpret = backend == "interpret"
+    refusal = (resample_kernels.CUMSUM_REFUSED if resampler == "systematic"
+               else resample_kernels.GATHER_REFUSED)
+    interpret = resample_kernels.interpret_only("fused SIR weight-phase",
+                                                refusal, backend)
     block = resample_kernels.pick_block(n)
     mat, unflatten = state_matrix(state)
     comb = resampler == "systematic"

@@ -2,8 +2,7 @@
 
 Functions, not module-level constants, so importing never touches jax
 device state (the dry-run must set XLA_FLAGS before first jax init).
-Construction goes through ``repro.core.runtime`` which resolves the
-installed JAX's mesh API (``axis_types`` support appeared mid-0.x).
+Construction goes through ``repro.core.runtime``.
 """
 from __future__ import annotations
 
@@ -17,6 +16,6 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_host_mesh(n: int | None = None, axis: str = "data"):
-    """1-D mesh over however many (CPU) devices exist — used by the PF
-    scaling benchmarks and tests."""
+    """1-D mesh over the first ``n`` devices (default: all), raising when
+    fewer exist — used by the PF drivers, benchmarks and tests."""
     return runtime.host_mesh(n, axis)

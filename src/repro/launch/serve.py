@@ -3,10 +3,11 @@
 Two front ends share this entry point:
 
 * ``--mode greedy|sample|smc`` — batched LM decoding (prefill + jitted
-  decode scan, or SMC particle decoding), optionally on a simulated
-  multi-device mesh.  Timing separates one-off compile from steady
-  state: ``--warmup`` runs (default 1, the ``benchmarks/pf_worker.py``
-  convention) execute before the measured window, and the reported
+  decode scan, or SMC particle decoding); ``--devices N`` exposes N
+  simulated devices on the CPU and is inert on an accelerator.  Timing
+  separates one-off compile from steady state: ``--warmup`` runs
+  (default 1, the ``benchmarks/pf_worker.py`` convention) execute
+  before the measured window, and the reported
   tok/s is pure steady-state — the compile seconds are printed on their
   own line instead of silently inflating the first measurement.
 * ``--mode sessions`` — the asyncio request plane (DESIGN.md §15): a
@@ -60,12 +61,12 @@ def main() -> None:
                     help="per-session mean frames/s")
     ap.add_argument("--max-delay", type=float, default=0.005,
                     help="scheduler deadline trigger in seconds")
-    ap.add_argument("--_respawned", action="store_true")
     args = ap.parse_args()
 
-    if args.devices > 1 and not args._respawned:
-        from repro.core import runtime
-        runtime.respawn_with_host_devices(args.devices, "repro.launch.serve")
+    from repro.core import runtime
+    if args.devices > 1:
+        runtime.simulate_host_devices(args.devices)
+    runtime.enable_compile_cache()
 
     if args.mode == "sessions":
         _serve_sessions(args)

@@ -7,8 +7,9 @@ code on the host-device mesh.  Fault-tolerance contract:
 
 * checkpoints are atomic and every k steps (``--ckpt-every``);
 * the data pipeline is (seed, step)-indexed — restart needs NO data state;
-* ``--devices N`` re-execs with a host-device mesh of N (testing elastic
-  restore: train on 4, resume on 8 — shardings are rebuilt at load).
+* ``--devices N`` trains on N devices — N simulated host devices on the
+  CPU, the first N chips on an accelerator (testing elastic restore:
+  train on 4, resume on 8 — shardings are rebuilt at load).
 
     PYTHONPATH=src python -m repro.launch.train --arch qwen3-32b \
         --smoke --devices 4 --steps 50 --batch 8 --seq 128
@@ -31,12 +32,12 @@ def main() -> None:
     ap.add_argument("--ckpt-dir", default="")
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--_respawned", action="store_true")
     args = ap.parse_args()
 
-    if args.devices > 1 and not args._respawned:
-        from repro.core import runtime
-        runtime.respawn_with_host_devices(args.devices, "repro.launch.train")
+    from repro.core import runtime
+    if args.devices > 1:
+        runtime.simulate_host_devices(args.devices)
+    runtime.enable_compile_cache()
 
     import jax
     import numpy as np
@@ -51,7 +52,7 @@ def main() -> None:
     from repro.train import TrainConfig, make_train_step
 
     cfg = get_config(args.arch, smoke=args.smoke)
-    devs = jax.devices()
+    devs = list(runtime.host_mesh(args.devices).devices)
     n = len(devs)
     # 2-D mesh when we have ≥4 devices: (data, model); else 1-D data
     if n >= 4:

@@ -5,8 +5,9 @@ all four DRA paths across refactors: any change to RNG consumption order,
 weight algebra, or resampling math shows up as a >1e-5 deviation in
 tests/test_parity.py (local SIR) and tests/test_distributed.py (DRAs).
 
-The goldens in-tree were produced by the pre-ensemble-refactor code (PR 1);
-only regenerate them when a *deliberate* numerical change is being made,
+The file records the JAX version that wrote it: JAX's PRNG defaults fix
+the random stream, so a JAX upgrade can move every trajectory.  Only
+regenerate it for a *deliberate* numerical change or such an upgrade,
 and say so in the commit.
 
     PYTHONPATH=src python tests/golden/generate_parity.py
@@ -131,8 +132,10 @@ def domain_golden() -> dict:
 if __name__ == "__main__":
     dest = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                         "sir_parity.json")
-    data = {"sir": sir_golden(), "dra": dra_golden(),
-            "domain": domain_golden()}
+    # the PRNG stream (and with it every trajectory) follows the JAX
+    # version's defaults, so the file records which one produced it
+    data = {"jax_version": jax.__version__, "sir": sir_golden(),
+            "dra": dra_golden(), "domain": domain_golden()}
     with open(dest, "w") as f:
         json.dump(data, f)
     print(f"wrote {dest}", file=sys.stderr)

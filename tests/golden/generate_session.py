@@ -70,7 +70,7 @@ def main() -> None:
     srv, h, _ = churn_run()
     res = srv.result(h)
     with open(DEST, "w") as f:
-        json.dump({"session": {
+        json.dump({"jax_version": jax.__version__, "session": {
             "estimates": np.asarray(res.estimates).tolist(),
             "ess": np.asarray(res.ess).tolist(),
             "log_marginal": np.asarray(res.log_marginal).tolist(),

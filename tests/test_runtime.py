@@ -1,5 +1,5 @@
-"""The version-portable runtime facade (repro.core.runtime) and the
-DRAConfig-selected Pallas resampling path."""
+"""The runtime facade (repro.core.runtime) and the DRAConfig-selected
+Pallas resampling path."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -16,8 +16,7 @@ KEY = jax.random.key(0)
 
 
 def test_shard_map_resolves_on_installed_jax():
-    """The facade finds a working shard_map on this JAX version (the whole
-    point: jax.shard_map moved between 0.4.x and 0.6+)."""
+    """The facade's shard_map runs a collective on the installed JAX."""
     mesh = runtime.host_mesh(1)
     f = runtime.shard_map(lambda x: runtime.psum(x, "data"), mesh,
                           in_specs=P("data"), out_specs=P())
@@ -26,7 +25,7 @@ def test_shard_map_resolves_on_installed_jax():
 
 def test_axis_size_is_static_int():
     """axis_size must come back as a python int (call sites use it in
-    range() and static shape arithmetic), on every JAX version."""
+    range() and static shape arithmetic)."""
     mesh = runtime.host_mesh(1)
 
     def body(x):
@@ -42,6 +41,26 @@ def test_axis_size_is_static_int():
 def test_make_mesh_portable():
     m = runtime.make_mesh((1, 1), ("data", "model"))
     assert m.shape == {"data": 1, "model": 1}
+
+
+def test_host_mesh_refuses_missing_devices():
+    """A mesh over more devices than exist raises instead of silently
+    shrinking (a 1-device mesh would skip the DRA altogether)."""
+    n = jax.device_count()
+    assert runtime.host_mesh(n).devices.size == n
+    with pytest.raises(RuntimeError, match="device"):
+        runtime.host_mesh(n + 1)
+
+
+def test_compile_cache_location():
+    """The default cache sits at a fixed, gitignored path at the checkout
+    root, and the CPU backend gets none."""
+    import pathlib
+    root = pathlib.Path(__file__).resolve().parent.parent
+    assert runtime.DEFAULT_CACHE_DIR == root / ".jax_cache"
+    assert ".jax_cache/" in (root / ".gitignore").read_text().split()
+    if jax.default_backend() == "cpu":
+        assert runtime.enable_compile_cache() is None
 
 
 def test_host_device_flag_replacement():
@@ -87,9 +106,32 @@ def test_backend_selection_rules():
     assert not dist.use_pallas_resample(
         DRAConfig(resample_backend="pallas"), jnp.asarray(1024))
     assert not dist.use_pallas_resample(DRAConfig(resample_backend="jnp"), 1024)
-    # "auto" on this (CPU) backend resolves to jnp; on TPU it would flip
-    if jax.default_backend() != "tpu":
-        assert not dist.use_pallas_resample(DRAConfig(), 1024)
+    # "auto" resolves to jnp on every platform: the kernel does not lower
+    # for TPU
+    assert not dist.use_pallas_resample(DRAConfig(), 1024)
+
+
+def test_explicit_pallas_request_for_unlowerable_kernel_raises():
+    """Kernels Mosaic refuses never run compiled: ``backend="pallas"``
+    raises instead of failing deep in the compiler, and the defaults take
+    XLA."""
+    from repro.kernels import ops, sir_fused
+    n = 1024
+    lw = jnp.zeros((n,))
+    y = jnp.full((n,), 8.0)
+    img = jnp.zeros((16, 16))
+    with pytest.raises(NotImplementedError, match="cumsum"):
+        sir_fused.fused_weight_step(lw, lw, jnp.zeros((n, 2)), KEY,
+                                    backend="pallas")
+    with pytest.raises(NotImplementedError, match="gather"):
+        sir_fused.fused_weight_step(lw, lw, jnp.zeros((n, 2)), KEY,
+                                    resampler="metropolis",
+                                    backend="pallas")
+    with pytest.raises(NotImplementedError, match="gather"):
+        ops.patch_log_likelihood(y, y, y, img, backend="pallas")
+    with pytest.raises(NotImplementedError, match="cumsum"):
+        ops.systematic_ancestors(lw, jnp.float32(0.5), backend="pallas")
+    assert sir_fused.default_backend() == "xla"
 
 
 @pytest.mark.parametrize("n,seed", [(256, 0), (1024, 1), (768, 2)])
