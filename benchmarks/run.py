@@ -28,9 +28,6 @@ SUITES = {
     "serve": ("benchmarks.bench_serve",
               "resident-session serving: occupancy/churn sweeps vs naive "
               "recompile baseline (BENCH_serve.json)"),
-    "latency": ("benchmarks.bench_latency",
-                "frontend load generator: Poisson/bursty arrival latency + "
-                "SLO capacity (BENCH_latency.json)"),
     "fleet": ("benchmarks.bench_fleet",
               "multi-bank fleet: 1-bank vs 2-bank-with-rebalancing under "
               "skewed Poisson load + migration cost (BENCH_fleet.json)"),

@@ -273,11 +273,12 @@ def _ring_exchange(state: Any, log_weights: Array, m_buf: int, m_valid: Array,
 
         return jax.tree_util.tree_map(a2a, s), a2a(lw)
 
-    if shuffle is None:
-        recv_state, recv_lw = ring((send_state, send_lw))
-    else:
-        recv_state, recv_lw = jax.lax.cond(shuffle, mix, ring,
-                                           (send_state, send_lw))
+    with jax.named_scope("ppf.exchange"):
+        if shuffle is None:
+            recv_state, recv_lw = ring((send_state, send_lw))
+        else:
+            recv_state, recv_lw = jax.lax.cond(shuffle, mix, ring,
+                                               (send_state, send_lw))
 
     keep = jnp.arange(m_buf) < m_valid
 
@@ -396,9 +397,10 @@ def rpa_resample(key: Array, ensemble: ParticleEnsemble, cfg: DRAConfig,
 
     # --- route compressed particles, merge, then expand locally
     # (deferred replica creation, paper §V.B)
-    route = dlb.route_compressed(comp, row_send, k_cap=cfg.k_cap,
-                                 axis_name=axis_name)
-    merged = dlb.merge_routed(comp, route)
+    with jax.named_scope("ppf.exchange"):
+        route = dlb.route_compressed(comp, row_send, k_cap=cfg.k_cap,
+                                     axis_name=axis_name)
+        merged = dlb.merge_routed(comp, route)
     out = particles.materialize(merged, c)
     stats = dlb.schedule_stats(schedule)
     return out, {
@@ -471,7 +473,9 @@ def butterfly_resample(key: Array, ensemble: ParticleEnsemble, cfg: DRAConfig,
     for k_s, perm in zip(keys, schedule):
         eff = particles.effective_log_weights(ens.log_weights, ens.counts)
         lz = jax.scipy.special.logsumexp(eff)
-        lz_p, lzr_p = runtime.grouped_ppermute((lz, lz_run), axis_name, perm)
+        with jax.named_scope("ppf.exchange"):
+            lz_p, lzr_p = runtime.grouped_ppermute((lz, lz_run), axis_name,
+                                                   perm)
         lz_run = jnp.logaddexp(lz_run, lzr_p) - jnp.log(2.0)
         pair = jnp.logaddexp(lz, lz_p)
         # dead-pair guard: both totals -inf → no units move either way
@@ -489,9 +493,10 @@ def butterfly_resample(key: Array, ensemble: ParticleEnsemble, cfg: DRAConfig,
             k_s, ens, n_tot, scheme=cfg.resampler,
             capacity=ens.capacity + cap, fill_log_weight=fill)
         pack = dlb.pack_slab(comp, m_send, k_cap=cap)
-        recv_state, recv_counts, recv_lw = runtime.grouped_ppermute(
-            (pack.slab_state, pack.slab_counts, pack.slab_log_weights),
-            axis_name, perm)
+        with jax.named_scope("ppf.exchange"):
+            recv_state, recv_counts, recv_lw = runtime.grouped_ppermute(
+                (pack.slab_state, pack.slab_counts, pack.slab_log_weights),
+                axis_name, perm)
 
         def cat(a, b):
             return jnp.concatenate([a, b], axis=0)

@@ -189,13 +189,16 @@ def ess_resample(key: Array, log_weights: Array, *, ess_frac: float,
     step, decoding only on resample) and stay at the call site.
     """
     n = log_weights.shape[0]
-    ess = effective_sample_size(log_weights)
-    log_z = jax.scipy.special.logsumexp(log_weights)
-    resampled = jnp.logical_or(ess < ess_frac * n, jnp.asarray(always))
-    counts = resampling.RESAMPLERS[resampler](key, log_weights, n, capacity=n)
-    ancestors = resampling.counts_to_ancestors(counts, n)
-    ancestors = jnp.where(resampled, ancestors,
-                          jnp.arange(n, dtype=ancestors.dtype))
+    with jax.named_scope("ppf.weights"):
+        ess = effective_sample_size(log_weights)
+        log_z = jax.scipy.special.logsumexp(log_weights)
+        resampled = jnp.logical_or(ess < ess_frac * n, jnp.asarray(always))
+    with jax.named_scope("ppf.resample"):
+        counts = resampling.RESAMPLERS[resampler](key, log_weights, n,
+                                                  capacity=n)
+        ancestors = resampling.counts_to_ancestors(counts, n)
+        ancestors = jnp.where(resampled, ancestors,
+                              jnp.arange(n, dtype=ancestors.dtype))
     return ResampleDecision(ancestors, ess, log_z, resampled)
 
 
@@ -243,22 +246,29 @@ def make_sir_step(model: ssm_base.StateSpaceModel, cfg: SIRConfig):
     def step(carry: SIRCarry, observation):
         key, ens = carry
         key, k_dyn, k_res = jax.random.split(key, 3)
-        ens = particles.advance(ens, k_dyn, model.transition_sample)
-        ens = particles.reweight(ens, model.observation_log_prob(ens.state,
-                                                                 observation))
-        est_ens = ens if est_fn is None else ens.replace(
-            state=est_fn(ens.state))
-        estimate = particles.weighted_mean(est_ens)
+        with jax.named_scope("ppf.advance"):
+            ens = particles.advance(ens, k_dyn, model.transition_sample)
+        with jax.named_scope("ppf.likelihood"):
+            ll = model.observation_log_prob(ens.state, observation)
+        with jax.named_scope("ppf.weights"):
+            ens = particles.reweight(ens, ll)
+            est_ens = ens if est_fn is None else ens.replace(
+                state=est_fn(ens.state))
+            estimate = particles.weighted_mean(est_ens)
 
         dec = ess_resample(k_res, ens.log_weights, ess_frac=cfg.ess_frac,
                            resampler=cfg.resampler,
                            always=cfg.always_resample)
-        state = (jax.tree_util.tree_map(lambda x: x[dec.ancestors], ens.state)
-                 if gather_fn is None else gather_fn(ens.state, dec.ancestors))
+        with jax.named_scope("ppf.gather"):
+            state = (jax.tree_util.tree_map(lambda x: x[dec.ancestors],
+                                            ens.state)
+                     if gather_fn is None
+                     else gather_fn(ens.state, dec.ancestors))
         # N·max(w): the weight-skew diagnostic the chain-resampler bias
         # gates consume (tests/stats.py ``chain_tv_profile``) — 1 at
         # uniform weights, N at full collapse.
-        skew = n * jnp.exp(jnp.max(ens.log_weights) - dec.log_z)
+        with jax.named_scope("ppf.weights"):
+            skew = n * jnp.exp(jnp.max(ens.log_weights) - dec.log_z)
         diag = {"weight_skew": skew}
         if cfg.record_ancestry:
             # pre-gather snapshot: ``ancestors[t]`` maps post-step slots
@@ -270,9 +280,10 @@ def make_sir_step(model: ssm_base.StateSpaceModel, cfg: SIRConfig):
         ancestors = dec.ancestors if cfg.record_ancestry else no_ancestors()
         # invariant: logsumexp(lw) == 0 entering every step, so ``log_z`` IS
         # the marginal-likelihood increment log p(z_k | Z^{k-1}).
-        lw = jnp.where(dec.resampled,
-                       jnp.full_like(ens.log_weights, -jnp.log(n)),
-                       ens.log_weights - dec.log_z)
+        with jax.named_scope("ppf.weights"):
+            lw = jnp.where(dec.resampled,
+                           jnp.full_like(ens.log_weights, -jnp.log(n)),
+                           ens.log_weights - dec.log_z)
         ens = ens.replace(state=state, log_weights=lw)
         out = StepOutput(estimate, dec.ess, dec.log_z, dec.resampled,
                          ancestors, diag)
@@ -294,13 +305,18 @@ def _make_fused_sir_step(model: ssm_base.StateSpaceModel, cfg: SIRConfig):
     def step(carry: SIRCarry, observation):
         key, ens = carry
         key, k_dyn, k_res = jax.random.split(key, 3)
-        ens = particles.advance(ens, k_dyn, model.transition_sample)
-        ll = model.observation_log_prob(ens.state, observation)
-        dec = sir_fused.fused_weight_step(
-            ens.log_weights, ll, ens.state, k_res,
-            resampler=cfg.resampler, ess_frac=cfg.ess_frac,
-            always=cfg.always_resample, backend=cfg.fused_backend)
-        state = jax.tree_util.tree_map(lambda x: x[dec.ancestors], ens.state)
+        with jax.named_scope("ppf.advance"):
+            ens = particles.advance(ens, k_dyn, model.transition_sample)
+        with jax.named_scope("ppf.likelihood"):
+            ll = model.observation_log_prob(ens.state, observation)
+        with jax.named_scope("ppf.weights"):
+            dec = sir_fused.fused_weight_step(
+                ens.log_weights, ll, ens.state, k_res,
+                resampler=cfg.resampler, ess_frac=cfg.ess_frac,
+                always=cfg.always_resample, backend=cfg.fused_backend)
+        with jax.named_scope("ppf.gather"):
+            state = jax.tree_util.tree_map(lambda x: x[dec.ancestors],
+                                           ens.state)
         ens = ens.replace(state=state, log_weights=dec.new_log_weights)
         out = StepOutput(dec.estimate, dec.ess, dec.log_z, dec.resampled,
                          no_ancestors(), {"weight_skew": dec.weight_skew})
@@ -353,50 +369,60 @@ def make_distributed_sir_step(model: ssm_base.StateSpaceModel, cfg: SIRConfig,
         n_total = c * p
         key, k_dyn, k_res = jax.random.split(key, 3)
 
-        ens = particles.advance(ens, k_dyn, model.transition_sample)
+        with jax.named_scope("ppf.advance"):
+            ens = particles.advance(ens, k_dyn, model.transition_sample)
         if domain is None:
-            ll = model.observation_log_prob(ens.state, observation)
+            with jax.named_scope("ppf.likelihood"):
+                ll = model.observation_log_prob(ens.state, observation)
             mig_diag = {}
         else:
             origin = domain.slab_origin(runtime.axis_index(axis_name))
 
             def tile_ll(state):
-                return tile_fn(state, observation, origin)
+                with jax.named_scope("ppf.likelihood"):
+                    return tile_fn(state, observation, origin)
 
-            ll, mig_diag = domain_mod.exchange_log_likelihood(
-                domain, ens, positions_fn(ens.state), tile_ll,
-                axis_name=axis_name)
-        ens = particles.reweight(ens, ll)
-        lw = ens.log_weights
-        max_ll = jnp.max(jnp.where(jnp.isfinite(lw), ll, -jnp.inf))
+            with jax.named_scope("ppf.exchange"):
+                ll, mig_diag = domain_mod.exchange_log_likelihood(
+                    domain, ens, positions_fn(ens.state), tile_ll,
+                    axis_name=axis_name)
+        with jax.named_scope("ppf.weights"):
+            ens = particles.reweight(ens, ll)
+            lw = ens.log_weights
+            max_ll = jnp.max(jnp.where(jnp.isfinite(lw), ll, -jnp.inf))
 
-        glz = dist.global_log_z(lw, axis_name)
-        ess = dist.global_ess(lw, axis_name)
+            glz = dist.global_log_z(lw, axis_name)
+            ess = dist.global_ess(lw, axis_name)
 
-        # MMSE estimate with globally normalized weights (one psum)
-        w = jnp.exp(jnp.where(jnp.isfinite(lw), lw - glz, -jnp.inf))
-        est_fn = getattr(model, "estimate_state", None)
-        est_state = ens.state if est_fn is None else est_fn(ens.state)
-        estimate = jax.tree_util.tree_map(
-            lambda x: runtime.psum(jnp.tensordot(w.astype(x.dtype), x, axes=1),
-                                   axis_name), est_state)
+            # MMSE estimate with globally normalized weights (one psum)
+            w = jnp.exp(jnp.where(jnp.isfinite(lw), lw - glz, -jnp.inf))
+            est_fn = getattr(model, "estimate_state", None)
+            est_state = ens.state if est_fn is None else est_fn(ens.state)
+            estimate = jax.tree_util.tree_map(
+                lambda x: runtime.psum(
+                    jnp.tensordot(w.astype(x.dtype), x, axes=1), axis_name),
+                est_state)
 
-        do_resample = jnp.logical_or(ess < cfg.ess_frac * n_total,
-                                     jnp.asarray(cfg.always_resample))
+            do_resample = jnp.logical_or(ess < cfg.ess_frac * n_total,
+                                         jnp.asarray(cfg.always_resample))
 
-        if dra.kind == "mpf":
-            r_ens, diag = dist.mpf_resample(k_res, ens, dra, axis_name)
-        elif dra.kind == "rna":
-            r_ens, diag = dist.rna_resample(k_res, ens, dra, axis_name)
-        elif dra.kind == "arna":
-            r_ens, diag = dist.arna_resample(k_res, ens, dra, axis_name,
-                                             max_ll)
-        elif dra.kind == "rpa":
-            r_ens, diag = dist.rpa_resample(k_res, ens, dra, axis_name)
-        elif dra.kind == "butterfly":
-            r_ens, diag = dist.butterfly_resample(k_res, ens, dra, axis_name)
-        else:
-            raise ValueError(dra.kind)
+        # each DRA resamples locally under ``ppf.resample``; its
+        # collectives carry ``ppf.exchange`` (``repro.core.distributed``)
+        with jax.named_scope("ppf.resample"):
+            if dra.kind == "mpf":
+                r_ens, diag = dist.mpf_resample(k_res, ens, dra, axis_name)
+            elif dra.kind == "rna":
+                r_ens, diag = dist.rna_resample(k_res, ens, dra, axis_name)
+            elif dra.kind == "arna":
+                r_ens, diag = dist.arna_resample(k_res, ens, dra, axis_name,
+                                                 max_ll)
+            elif dra.kind == "rpa":
+                r_ens, diag = dist.rpa_resample(k_res, ens, dra, axis_name)
+            elif dra.kind == "butterfly":
+                r_ens, diag = dist.butterfly_resample(k_res, ens, dra,
+                                                      axis_name)
+            else:
+                raise ValueError(dra.kind)
 
         # fold the weight-phase collectives into the DRA's comm accounting
         # (DESIGN.md §14.3): logZ gather + ESS gather/psum + estimate psum.
@@ -407,9 +433,10 @@ def make_distributed_sir_step(model: ssm_base.StateSpaceModel, cfg: SIRConfig,
                 "comm_stages": diag["comm_stages"] + 4}
 
         # select keeps SPMD collective schedule static (DESIGN.md §2.3)
-        kept = ens.replace(log_weights=lw - glz)
-        ens = jax.tree_util.tree_map(
-            lambda a, b: jnp.where(do_resample, a, b), r_ens, kept)
+        with jax.named_scope("ppf.resample"):
+            kept = ens.replace(log_weights=lw - glz)
+            ens = jax.tree_util.tree_map(
+                lambda a, b: jnp.where(do_resample, a, b), r_ens, kept)
 
         # the DRA paths exchange (state, multiplicity) pairs, not ancestor
         # indices — genealogy recording is a single-device/bank feature.

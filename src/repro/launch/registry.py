@@ -14,9 +14,9 @@ vocabulary that layer shares:
   scale-out.
 * Placement policies — ``LeastLoaded`` (default) and
   ``CapacityTierAware`` pick a destination bank from ``BankView`` load
-  snapshots (occupancy, queue depth, step time, ESS — all sourced from
-  ``repro.serve.metrics`` series).  Policies are pure functions of the
-  views, so they are unit-testable without a single jitted program.
+  snapshots (live streams, occupancy, queue depth).  Policies are pure
+  functions of the views, so they are unit-testable without a single
+  jitted program.
 """
 from __future__ import annotations
 
@@ -131,9 +131,8 @@ class FleetRegistry:
 class BankView:
     """Load snapshot of one live bank, as placement policies see it.
 
-    Built by the fleet controller from the bank's
-    ``repro.serve.metrics`` snapshot each time a placement or rebalance
-    decision is made.
+    Built by the fleet controller from the bank's streams and server
+    each time a placement or rebalance decision is made.
 
     Attributes:
       name: bank name (what ``choose`` returns).
@@ -142,11 +141,6 @@ class BankView:
         (may exceed ``capacity`` — the overflow is parked).
       occupancy: attached sessions (≤ ``capacity``).
       queue_depth: undelivered frames across the bank's streams.
-      step_ms_p50: median bank-step wall time (ms) over the metrics
-        window (0 before the first step).
-      ess_mean: mean per-frame ESS over the window (0 before the first
-        frame) — a quality signal: a bank whose sessions degenerate
-        together is doing harder inference per frame.
     """
 
     name: str
@@ -154,8 +148,6 @@ class BankView:
     live_streams: int
     occupancy: int
     queue_depth: int
-    step_ms_p50: float = 0.0
-    ess_mean: float = 0.0
 
     @property
     def load(self) -> float:

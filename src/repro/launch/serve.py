@@ -13,10 +13,10 @@ Two front ends share this entry point:
 * ``--mode sessions`` — the asyncio request plane (DESIGN.md §15): a
   ``ParticleFrontend`` over a resident ``ParticleSessionServer`` bank,
   driven by a synthetic Poisson client fleet, reporting p50/p99
-  per-frame latency and the scheduler's operational counters.  The
-  whole report reads from the frontend's ``Metrics`` snapshot — the
-  same series the fleet controller and ``benchmarks/bench_latency.py``
-  consume — so there is exactly one accounting path to trust.
+  per-frame latency, the scheduler's operational counters, and the
+  seconds its loop and its step executor spent in each ``ppf.*`` span.
+  The whole report reads from the frontend's ``Metrics`` snapshot, so
+  there is exactly one accounting path to trust.
 * ``--mode fleet`` — the multi-bank controller (DESIGN.md §16): two
   active banks plus a standby, skewed Poisson clients with mid-run
   churn, printing the migration/scale counters and the per-bank
@@ -160,18 +160,24 @@ def _lg_demo_model():
 
 def _print_plane_report(snap: dict, label: str) -> None:
     """Render one request plane's report from its ``Metrics`` snapshot —
-    frames, latency percentiles, and park/resume counts all come from
-    the same snapshot the scheduler maintains (no shadow accounting)."""
+    frames, latency percentiles, park/resume counts, the mean queue
+    wait and the seconds in each ``ppf.*`` span all come from the same
+    snapshot the scheduler maintains (no shadow accounting)."""
     c = snap["counters"]
     lat = snap["series"].get("latency", {})
     coalesce = snap["series"].get("coalesce", {})
-    print(f"{label} frames={c.get('frames', 0):.0f} "
+    frames = c.get("frames", 0)
+    print(f"{label} frames={frames:.0f} "
           f"p50={lat.get('p50', 0.0) * 1e3:.1f}ms "
           f"p99={lat.get('p99', 0.0) * 1e3:.1f}ms "
           f"steps={c.get('steps', 0):.0f} "
           f"coalesce_mean={coalesce.get('mean', 0.0):.2f} "
           f"parks={c.get('park_events', 0):.0f} "
           f"resumes={c.get('resume_events', 0):.0f}")
+    wait = c.get("queue_wait_s", 0.0) / frames if frames else 0.0
+    spans = " ".join(f"{name[4:]}={c[name]:.3f}s" for name in sorted(c)
+                     if name.startswith("ppf."))
+    print(f"{label} queue_wait_mean={wait * 1e3:.1f}ms {spans}")
 
 
 def _serve_sessions(args) -> None:

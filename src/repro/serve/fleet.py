@@ -13,8 +13,8 @@ sit on its own capacity tier or emulated mesh, decided by the
 
 * **places** new streams on banks through a pluggable policy
   (``repro.launch.registry``: ``LeastLoaded`` default,
-  ``CapacityTierAware``), fed by per-bank ``repro.serve.metrics``
-  views — occupancy, queue depth, step-time p50, mean ESS;
+  ``CapacityTierAware``), fed by one ``BankView`` per bank — live
+  streams, occupancy, queue depth;
 * **rebalances** live: when residency pressure skews past
   ``imbalance_threshold``, sessions migrate hottest-bank → coldest-bank
   through suspend → ``checkpoint/store`` → resume (the bitwise-pinned
@@ -537,17 +537,14 @@ class FleetController:
         asyncio.ensure_future(_guarded())
 
     def _view(self, bank: _Bank) -> registry_mod.BankView:
-        """Build the placement-policy load view from the bank's
-        metrics snapshot (§16.1)."""
+        """Build the placement-policy load view of ``bank`` from its
+        streams and its server (§16.1)."""
         live = [self._streams[i] for i in bank.streams
                 if not self._streams[i].closed]
-        series = bank.fe.metrics.snapshot()["series"]
         return registry_mod.BankView(
             name=bank.spec.name, capacity=bank.spec.capacity,
             live_streams=len(live), occupancy=bank.server.occupancy,
-            queue_depth=sum(fs.queue_depth for fs in live),
-            step_ms_p50=series.get("step_ms", {}).get("p50", 0.0),
-            ess_mean=series.get("ess", {}).get("mean", 0.0))
+            queue_depth=sum(fs.queue_depth for fs in live))
 
     # -- internals: the frame pump ------------------------------------------
     def _kick(self, fs: FleetStream) -> None:

@@ -23,9 +23,12 @@ occupancy tier (DESIGN.md §15.2).  The control plane is:
   Per-stream queues longer than ``max_queue`` make ``submit`` await —
   backpressure reaches the client as latency, not as dropped frames.
 * **Observability**: every decision increments ``repro.serve.metrics``
-  counters/series (queue depth, coalesce factor, park/resume events,
-  per-frame latency); ``snapshot()`` merges the server's tier-hit and
-  trace counters.
+  counters/series (coalesce factor, step time, park/resume events,
+  per-frame latency and queue wait); ``snapshot()`` merges the server's
+  tier-hit and trace counters.  ``Metrics.span`` times each phase of a
+  pass as a ``ppf.serve.*`` (loop thread) or ``ppf.bank.*`` (executor)
+  profiler span and counter; every span of one step carries its
+  ``step`` number, so the two threads' spans join in a trace.
 
 Threading contract: the frontend owns its server.  Bank steps and tier
 warmup run in ONE single-thread executor per frontend so the event
@@ -55,8 +58,10 @@ Lifecycle::
 from __future__ import annotations
 
 import asyncio
+import collections
 import concurrent.futures
 import dataclasses
+import functools
 import itertools
 import os
 import tempfile
@@ -113,7 +118,7 @@ class FrameResult:
       log_marginal: this frame's log-marginal-likelihood increment.
       resampled: whether the ESS trigger fired a resampling pass.
       latency: seconds from ``submit`` to result delivery (queueing +
-        coalescing + compute — the number BENCH_latency.json quantiles).
+        coalescing + compute; the ``latency`` series of the metrics).
     """
 
     estimate: np.ndarray
@@ -203,6 +208,8 @@ class ParticleFrontend:
         self._executor = executor or concurrent.futures.ThreadPoolExecutor(
             max_workers=1, thread_name_prefix="ppf-frontend")
         self._stepping: set[int] = set()     # sids inside the running step
+        self._step_numbers = itertools.count(1)  # the ``step`` of the spans
+        self._step = 0                       # number of the running step
         self._step_complete = asyncio.Event()
         self.last_step_at: float | None = None   # loop-clock end of last step
 
@@ -396,10 +403,15 @@ class ParticleFrontend:
 
     async def _schedule_forever(self) -> None:
         loop = asyncio.get_running_loop()
+        span = self.metrics.span
         while True:
             now = loop.time()
-            self._reap_closed()
-            self._rebalance(now)
+            with span("serve.slots") as annotation:
+                self._reap_closed()
+                moved = self._rebalance(now)
+                annotation.set_metadata(attached=moved["attached"],
+                                        resumed=moved["resumed"],
+                                        parked=moved["parked"])
             ready = [st for st in self._streams.values()
                      if st.attached and st._pending and not st._closed
                      and not st._migrating]
@@ -409,8 +421,11 @@ class ParticleFrontend:
             if not ready:
                 if not waiting:
                     self._idle.set()
-                await self._wait_for_wake(None if not waiting
-                                          else self.config.park_patience)
+                    with span("serve.idle"):
+                        await self._wait_for_wake(None)
+                else:
+                    with span("serve.hold", pending=len(waiting)):
+                        await self._wait_for_wake(self.config.park_patience)
                 continue
             oldest = min(st._pending[0][2] for st in ready)
             live = sum(1 for st in self._streams.values() if not st._closed)
@@ -418,21 +433,22 @@ class ParticleFrontend:
                          self.server.capacity, live)
             deadline = oldest + self.config.max_delay
             if len(ready) < target and now < deadline:
-                await self._wait_for_wake(deadline - now)
+                with span("serve.hold", pending=len(ready)):
+                    await self._wait_for_wake(deadline - now)
                 continue
             work = []
             for st in ready:
                 frame, fut, t_arrive = st._pending.pop(0)
                 st._not_full.set()
                 work.append((st, frame, fut, t_arrive))
-            self.metrics.observe("queue_depth", sum(
-                st.queue_depth for st in self._streams.values()))
             self.metrics.observe("coalesce", len(work))
             self._stepping = {st.sid for st, _, _, _ in work}
+            n = self._step = next(self._step_numbers)
             t_fire = loop.time()
             try:
-                rows = await loop.run_in_executor(
-                    self._executor, self._fire, work)
+                with span("serve.step", step=n, sessions=len(work)):
+                    rows = await loop.run_in_executor(
+                        self._executor, self._fire, work)
             finally:
                 self._stepping = set()
                 # wake handoff quiescers even when the step failed —
@@ -440,19 +456,20 @@ class ParticleFrontend:
                 self._step_complete.set()
                 self._step_complete.clear()
             done = loop.time()
-            self.last_step_at = done
-            self.metrics.inc("steps")
-            self.metrics.observe("step_ms", (done - t_fire) * 1e3)
-            for (st, _, fut, t_arrive), row in zip(work, rows):
-                st._last_active = done
-                latency = done - t_arrive
-                self.metrics.inc("frames")
-                self.metrics.observe("latency", latency)
-                self.metrics.observe("ess", row[1])
-                if not fut.done():
-                    fut.set_result(FrameResult(
-                        estimate=row[0], ess=row[1], log_marginal=row[2],
-                        resampled=row[3], latency=latency))
+            with span("serve.deliver", step=n):
+                self.last_step_at = done
+                self.metrics.inc("steps")
+                self.metrics.observe("step_ms", (done - t_fire) * 1e3)
+                for (st, _, fut, t_arrive), row in zip(work, rows):
+                    st._last_active = done
+                    latency = done - t_arrive
+                    self.metrics.inc("frames")
+                    self.metrics.inc("queue_wait_s", t_fire - t_arrive)
+                    self.metrics.observe("latency", latency)
+                    if not fut.done():
+                        fut.set_result(FrameResult(
+                            estimate=row[0], ess=row[1], log_marginal=row[2],
+                            resampled=row[3], latency=latency))
 
     async def _wait_for_wake(self, timeout: float | None) -> None:
         """Sleep until new work arrives or ``timeout`` elapses."""
@@ -464,16 +481,21 @@ class ParticleFrontend:
 
     def _fire(self, work: list[tuple]) -> list[tuple]:
         """(worker thread) Submit one frame per ready stream, run ONE
-        bank step, and read each stream's freshest outputs to host."""
-        for st, frame, _, _ in work:
-            self.server.submit(st._session, frame)
-        self.server.step()
-        rows = []
-        for st, _, _, _ in work:
-            est, ess, log_z, res = self.server.latest(st._session)[:4]
-            # est is already host NumPy (a pytree for models whose
-            # estimate is structured, e.g. the LM decode adapter)
-            rows.append((est, float(ess), float(log_z), bool(res)))
+        bank step, and read each stream's freshest outputs to host; the
+        ``ppf.bank.*`` spans carry the running step's number."""
+        span = functools.partial(self.metrics.span, step=self._step)
+        with span("bank.step", sessions=len(work),
+                  tier=self.server.tier_for(len(work))):
+            for st, frame, _, _ in work:
+                self.server.submit(st._session, frame)
+            self.server.step(span=span)
+            rows = []
+            with span("bank.read", sessions=len(work)):
+                for st, _, _, _ in work:
+                    est, ess, log_z, res = self.server.latest(st._session)[:4]
+                    # est is already host NumPy (a pytree for models whose
+                    # estimate is structured, e.g. the LM decode adapter)
+                    rows.append((est, float(ess), float(log_z), bool(res)))
         return rows
 
     # -- slot management (admission control, §15.3) -------------------------
@@ -485,17 +507,19 @@ class ParticleFrontend:
                 self.server.detach(st._session)
                 st._session = None
 
-    def _rebalance(self, now: float) -> None:
+    def _rebalance(self, now: float) -> collections.Counter:
         """Assign slots: attach/resume waiting streams into free slots,
         park idle residents to make room, and force-rotate when parked
-        work has waited past ``park_patience``."""
+        work has waited past ``park_patience``.  Returns how many streams
+        were ``attached``, ``resumed`` and ``parked``."""
+        moved = collections.Counter()
         waiting = sorted((st for st in self._streams.values()
                           if not st.attached and st._pending
                           and not st._closed and not st._migrating),
                          key=lambda st: st._wait_since or now)
         for st in waiting:
             if self.server.occupancy < self.server.capacity:
-                self._give_slot(st, now)
+                moved[self._give_slot(st, now)] += 1
                 continue
             victim = self._pick_victim(
                 require_idle=(now - (st._wait_since or now)
@@ -503,7 +527,8 @@ class ParticleFrontend:
             if victim is None:
                 break                       # nobody safely evictable yet
             self._park(victim)
-            self._give_slot(st, now)
+            moved["parked"] += 1
+            moved[self._give_slot(st, now)] += 1
         # spare slots warm up idle (frameless) streams so their first
         # frame skips the attach on the hot path
         for st in self._streams.values():
@@ -511,17 +536,22 @@ class ParticleFrontend:
                 break
             if not st.attached and not st._closed and not st._pending \
                     and not st._migrating:
-                self._give_slot(st, now)
+                moved[self._give_slot(st, now)] += 1
+        return moved
 
-    def _give_slot(self, st: StreamHandle, now: float) -> None:
+    def _give_slot(self, st: StreamHandle, now: float) -> str:
+        """Attach or resume ``st`` into a free slot; returns which."""
         if st._sus is not None:                 # resume a parked session
             st._session = self.server.resume(st._sus)
             st._sus = None
             self.metrics.inc("resume_events")
+            how = "resumed"
         else:                                   # first attach
             st._session = self.server.attach(st._key)
+            how = "attached"
         st._wait_since = None
         st._last_active = now
+        return how
 
     def _pick_victim(self, require_idle: bool) -> StreamHandle | None:
         """The least-recently-active resident stream; with

@@ -1,12 +1,16 @@
-"""Lightweight serving metrics: counters and windowed timers.
+"""Lightweight serving metrics: counters, windowed timers and spans.
 
-The request plane (``repro.serve.frontend``) and its benchmarks need a
-handful of operational numbers — queue depth, coalesce factor, tier
-hits, park/resume events, per-frame latency — without dragging in a
-metrics dependency.  ``Metrics`` keeps monotonic counters plus bounded
+The request plane (``repro.serve.frontend``) needs a handful of
+operational numbers — coalesce factor, step time, park/resume events,
+per-frame latency, time spent waiting in the queue — without dragging in
+a metrics dependency.  ``Metrics`` keeps monotonic counters plus bounded
 sample windows and renders everything as one plain ``snapshot()`` dict
-(JSON-ready, what ``benchmarks/bench_latency.py`` embeds in
-``BENCH_latency.json``).
+(JSON-ready; ``repro.launch.serve`` prints its plane report from it).
+
+``span`` times one phase of the plane twice over: as a
+``jax.profiler.TraceAnnotation`` named ``ppf.<name>``, which lands on the
+device trace's clock when a profiler runs, and as the counter of the same
+name, which sums the phase's seconds whether or not one runs.
 
 Quantiles are computed over the most recent ``window`` samples per
 series (a ring buffer, so a long-lived server's memory stays bounded);
@@ -15,8 +19,11 @@ series (a ring buffer, so a long-lived server's memory stays bounded);
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
+import time
 
+import jax
 import numpy as np
 
 
@@ -60,10 +67,12 @@ class Metrics:
     """A named bag of counters and sample series.
 
     ``inc`` bumps a monotonic counter; ``observe`` records one sample of
-    a distribution (latency seconds, batch sizes, queue depths, ...).
-    ``snapshot`` renders both as a nested plain dict.  Single-threaded
-    by design: the request plane touches it only from the event loop /
-    scheduler, never from worker threads.
+    a distribution (latency seconds, batch sizes, ...); ``span`` times a
+    block into a counter and the profiler's trace.  ``snapshot`` renders
+    counters and series as a nested plain dict.  No locks: every counter
+    and series has one writing thread (the request plane's event loop,
+    or for the ``ppf.bank.*`` spans its step executor), and ``snapshot``
+    copies in one call under the interpreter lock.
     """
 
     def __init__(self, window: int = 4096):
@@ -81,6 +90,23 @@ class Metrics:
         if series is None:
             series = self._series[name] = _Series(self._window)
         series.add(value)
+
+    @contextlib.contextmanager
+    def span(self, name: str, **args):
+        """Time the block as the trace event ``ppf.<name>`` with ``args``
+        as its stats, and add its seconds to the counter ``ppf.<name>``.
+
+        Yields the ``TraceAnnotation``, whose ``set_metadata`` adds stats
+        known only inside the block.  The counter is added to also when
+        the block raises.
+        """
+        full = "ppf." + name
+        t0 = time.perf_counter()
+        try:
+            with jax.profiler.TraceAnnotation(full, **args) as annotation:
+                yield annotation
+        finally:
+            self.inc(full, time.perf_counter() - t0)
 
     def counter(self, name: str) -> float:
         """Current value of counter ``name`` (0 if never incremented)."""

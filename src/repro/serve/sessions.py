@@ -33,6 +33,7 @@ different capacity, a different mesh, or in a different process.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import heapq
 import itertools
@@ -48,6 +49,11 @@ from repro.core import filters, particles, runtime, smc
 from repro.models.ssm import base as ssm_base
 
 Array = jax.Array
+
+
+def _untimed(name: str, **args):
+    """The ``span`` of an untimed ``step``: brackets nothing."""
+    return contextlib.nullcontext()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -269,10 +275,12 @@ class ParticleSessionServer:
             # their write-back a value-level no-op.  jit keys its cache
             # by the (T,) shape: one trace + executable per tier ever.
             self._step_traces += 1      # trace-time side effect only
-            sub = jax.tree_util.tree_map(lambda c: c[idx], carry)
+            with jax.named_scope("ppf.tier.gather"):
+                sub = jax.tree_util.tree_map(lambda c: c[idx], carry)
             sub, outs = bank_step(sub, (frames, active))
-            carry = jax.tree_util.tree_map(
-                lambda c, x: c.at[idx].set(x), carry, sub)
+            with jax.named_scope("ppf.tier.scatter"):
+                carry = jax.tree_util.tree_map(
+                    lambda c, x: c.at[idx].set(x), carry, sub)
             return carry, outs
 
         self._tier_fn = jax.jit(tier_fn, donate_argnums=(0,))
@@ -371,7 +379,13 @@ class ParticleSessionServer:
                 f"space)")
         sess.queue.append(frame)
 
-    def step(self) -> int:
+    def tier_for(self, ready: int) -> int:
+        """The slot count of the program a tick with ``ready`` sessions
+        runs: the smallest occupancy tier covering them (the full
+        capacity on a mesh server)."""
+        return next(t for t in self.tiers if t >= ready)
+
+    def step(self, span=None) -> int:
         """Advance every slot with a pending frame by ONE frame.
 
         Single-device servers run the smallest occupancy-tier program
@@ -379,42 +393,46 @@ class ParticleSessionServer:
         DESIGN.md §15.2); mesh servers run the one full-capacity program.
         Either way it is one program launch per tick.  Returns the number
         of sessions stepped (0 = nothing pending, no launch).
+
+        ``span``, when given, is a context-manager factory
+        ``span(name, **args)`` (the request plane passes its
+        ``Metrics.span``) that brackets the tick's two phases:
+        ``"bank.stage"`` (the frame batch, the route, the upload) and
+        ``"bank.dispatch"`` (the jitted call until it returns, with the
+        ``tier`` it runs).
         """
-        ready = sorted((s for s in self._sessions.values() if s.queue),
-                       key=lambda s: s.slot)
-        if not ready:
-            return 0
-        if self.mesh is not None:
-            return self._step_full(ready)
-        return self._step_tiered(ready)
-
-    def _step_full(self, ready: list[_Session]) -> int:
-        """One full-capacity launch (the mesh path: slot order is the
-        shard layout, so slots stay in place and inactivity is a mask)."""
-        shape, dtype = self._frame_spec
-        frames = np.zeros((self.capacity,) + shape, dtype)
-        active = np.zeros((self.capacity,), bool)
-        for sess in ready:
-            frames[sess.slot] = sess.queue.pop(0)
-            active[sess.slot] = True
-        self.tier_hits[self.capacity] += 1
-        self._carry, outs = self._step_fn(self._carry, jnp.asarray(frames),
-                                          jnp.asarray(active))
-        self._record_outputs(ready, [s.slot for s in ready], outs)
-        return len(ready)
-
-    def _step_tiered(self, ready: list[_Session]) -> int:
-        """Gather-step-scatter through the smallest covering tier."""
-        tier = next(t for t in self.tiers if t >= len(ready))
-        shape, dtype = self._frame_spec
-        frames = np.zeros((tier,) + shape, dtype)
-        for row, sess in enumerate(ready):
-            frames[row] = sess.queue.pop(0)
-        idx, active = self._route(tier, tuple(s.slot for s in ready))
+        span = span or _untimed
+        with span("bank.stage"):
+            ready = sorted((s for s in self._sessions.values() if s.queue),
+                           key=lambda s: s.slot)
+            if not ready:
+                return 0
+            tier = self.tier_for(len(ready))
+            shape, dtype = self._frame_spec
+            frames = np.zeros((tier,) + shape, dtype)
+            if self.mesh is None:
+                # gather the ready slots into the tier's compact bank
+                rows = range(len(ready))
+                idx, active = self._route(tier, tuple(s.slot for s in ready))
+            else:
+                # slot order is the shard layout: slots stay in place and
+                # inactivity is a mask
+                rows = [s.slot for s in ready]
+                mask = np.zeros((tier,), bool)
+                mask[rows] = True
+                active = jnp.asarray(mask)
+            for row, sess in zip(rows, ready):
+                frames[row] = sess.queue.pop(0)
+            frames = jnp.asarray(frames)
         self.tier_hits[tier] += 1
-        self._carry, outs = self._tier_fn(self._carry, idx,
-                                          jnp.asarray(frames), active)
-        self._record_outputs(ready, range(len(ready)), outs)
+        with span("bank.dispatch", tier=tier):
+            if self.mesh is None:
+                self._carry, outs = self._tier_fn(self._carry, idx, frames,
+                                                  active)
+            else:
+                self._carry, outs = self._step_fn(self._carry, frames,
+                                                  active)
+        self._record_outputs(ready, rows, outs)
         return len(ready)
 
     def _route(self, tier: int, slots: tuple) -> tuple:
