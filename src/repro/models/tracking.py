@@ -61,14 +61,45 @@ def render_spot(yx: Array, intensity: Array, cfg: TrackingConfig,
     return intensity * jnp.exp(-d2 / (2.0 * cfg.sigma_psf ** 2))
 
 
+def patch_table(frame: Array, radius: int) -> Array:
+    """Every ±``radius`` patch that fits in ``frame``, one per row.
+
+    Row ``sy * tw + sx`` holds ``frame[sy:sy+k, sx:sx+k].ravel()`` with
+    ``k = 2·radius + 1`` and ``tw = W − 2·radius``; the shape is
+    ``((H − 2·radius)·(W − 2·radius), k²)``.  The rows are copies of
+    pixels, so a patch read from the table is exactly the patch sliced
+    from the frame.
+
+    The table is built as ``(k², rows)``, ``k`` row shifts and then ``k``
+    column shifts, and transposed once: stacking the ``k²`` shifted
+    frames on the minor axis instead makes XLA lay out each of them with
+    its one column padded to the TPU's 128 lanes (10.7 GB of temporaries
+    at 512², compiled for a TPU v5e).
+    """
+    k = 2 * radius + 1
+    h, w = frame.shape
+    th, tw = h - 2 * radius, w - 2 * radius
+    rows = jnp.stack([frame[i:i + th] for i in range(k)])           # (k, th, w)
+    shifts = jnp.stack([rows[:, :, j:j + tw] for j in range(k)], axis=1)
+    return shifts.reshape(k * k, th * tw).T                         # (th·tw, k²)
+
+
 def patch_log_likelihood(state: Array, frame: Array, cfg: TrackingConfig, *,
                          center_bounds: tuple | None = None,
                          frame_origin: tuple | None = None) -> Array:
     """Log-likelihood (paper Eq. 4) for a batch of particles against one
-    frame, each evaluated on its own ±R patch.  Pure-jnp reference; the
-    Pallas kernel in ``repro.kernels.patch_likelihood`` accelerates this.
+    frame, each evaluated on its own ±R patch.
 
     state: (N, 5) [y, x, vy, vx, I0];  frame: (H, W).
+
+    The patches come from one table of every patch of the frame
+    (``patch_table``, built once per frame) by one gather of one whole
+    row per particle; the model and the sum then run on the
+    ``(N, 2R+1, 2R+1)`` patches.  The table holds copies of pixels, so
+    every particle reads the bits a per-particle ``dynamic_slice`` of the
+    frame reads, and the result is that form's, bit for bit.  A per-particle window gather, that vmapped
+    ``dynamic_slice`` included, runs on the TPU as a serial loop over
+    particles; a row gather does not.
 
     The two keyword extras are the domain-decomposition hooks
     (DESIGN.md §10.2); ``frame`` may then be a halo *slab* of the full
@@ -76,7 +107,9 @@ def patch_log_likelihood(state: Array, frame: Array, cfg: TrackingConfig, *,
 
     center_bounds: (lo_y, hi_y, lo_x, hi_x) clamp for the patch-center
         pixel in FRAME coordinates, overriding the default frame interior
-        ``[R, dim-1-R]``.
+        ``[R, dim-1-R]``.  The bounds keep each patch inside ``frame`` (the
+        default and ``tile_patch_log_likelihood``'s do); a patch they let
+        overhang reads the nearest one that fits.
     frame_origin: frame coordinates (oy, ox) of ``frame[0, 0]``.  Only
         the integer patch *gather* is offset by the origin — positions,
         centers, and the PSF model all stay in frame coordinates, so a
@@ -85,6 +118,7 @@ def patch_log_likelihood(state: Array, frame: Array, cfg: TrackingConfig, *,
         when it crosses a binade).
     """
     r = cfg.patch_radius
+    k = 2 * r + 1
     dy, dx = psf_patch_offsets(r)                       # (2R+1, 2R+1)
     h, w = frame.shape
     if center_bounds is None:
@@ -93,12 +127,16 @@ def patch_log_likelihood(state: Array, frame: Array, cfg: TrackingConfig, *,
         lo_y, hi_y, lo_x, hi_x = center_bounds
     oy, ox = (0, 0) if frame_origin is None else frame_origin
 
-    def one(s):
+    cy = jnp.clip(jnp.round(state[:, 0]).astype(jnp.int32), lo_y, hi_y)
+    cx = jnp.clip(jnp.round(state[:, 1]).astype(jnp.int32), lo_x, hi_x)
+    th, tw = h - 2 * r, w - 2 * r
+    row = (jnp.clip(cy - r - oy, 0, th - 1) * tw
+           + jnp.clip(cx - r - ox, 0, tw - 1))
+    patches = jnp.take(patch_table(frame, r), row, axis=0,
+                       mode="clip").reshape(-1, k, k)
+
+    def one(s, cy, cx, patch):
         y, x, i0 = s[0], s[1], s[4]
-        cy = jnp.clip(jnp.round(y).astype(jnp.int32), lo_y, hi_y)
-        cx = jnp.clip(jnp.round(x).astype(jnp.int32), lo_x, hi_x)
-        patch = jax.lax.dynamic_slice(frame, (cy - r - oy, cx - r - ox),
-                                      (2 * r + 1, 2 * r + 1))
         py = cy + dy
         px = cx + dx
         model = i0 * jnp.exp(-((py - y) ** 2 + (px - x) ** 2)
@@ -110,7 +148,7 @@ def patch_log_likelihood(state: Array, frame: Array, cfg: TrackingConfig, *,
         return (jnp.sum(patch * model) - 0.5 * jnp.sum(model * model)) / (
             cfg.sigma_like ** 2)
 
-    return jax.vmap(one)(state)
+    return jax.vmap(one)(state, cy, cx, patches)
 
 
 def tile_patch_log_likelihood(state: Array, slab: Array, origin_yx,
