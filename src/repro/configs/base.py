@@ -13,19 +13,33 @@ from typing import Optional
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
-    n_experts: int
+    n_experts: int                  # routed experts: the router's width
     top_k: int
     d_ff_expert: int
     n_shared_experts: int = 0
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25   # training dispatch only
     router_aux_loss: float = 0.001
     first_dense_layers: int = 0     # leading dense layers (deepseek-style)
+    # router (models/lm/moe.py ``route``): "softmax" or "sigmoid" scores;
+    # a per-expert selection bias added to the scores to choose the top-k
+    # and not to weight them (DeepSeek-V3 ``noaux_tc``); the chosen
+    # scores normalised to sum 1, then scaled by ``routed_scale``
+    scoring: str = "softmax"
+    selection_bias: bool = False
+    routed_scale: float = 1.0
+    # the experts held here: share ``expert_share`` of ``expert_shares``
+    # equal contiguous shares of the ``n_experts`` (expert parallelism
+    # with this chip's part only; the router still spans all of them)
+    expert_shares: int = 1
+    expert_share: int = 0
     # "xla"         — dense gather/scatter, SPMD partitioner chooses comms
     #                 (baseline; replicates token buffers — see §Perf).
-    # "ep_shardmap" — explicit expert-parallel routing: fixed-capacity
-    #                 per-expert send buffers moved by ONE all_to_all over
-    #                 the data axis (the paper's §IV/§V DLB executor applied
-    #                 to MoE tokens), expert FFN row/col-split over model.
+    # "ep_shardmap" — explicit expert-parallel routing: in training
+    #                 fixed-capacity per-expert send buffers moved by ONE
+    #                 all_to_all over the data axis (the paper's §IV/§V DLB
+    #                 executor applied to MoE tokens); in serving each data
+    #                 shard is one expert share, run on the rows gathered
+    #                 over data; expert FFN row/col-split over model.
     dispatch: str = "xla"
     # expert-output reduction over the model axis (ep_shardmap only):
     # "psum"  — all-reduce the full-D output buffer (baseline);
@@ -37,7 +51,7 @@ class MoEConfig:
 @dataclasses.dataclass(frozen=True)
 class MLAConfig:
     kv_lora_rank: int = 512
-    q_lora_rank: int = 1536
+    q_lora_rank: int = 1536         # 0: a direct query projection
     qk_nope_dim: int = 128
     qk_rope_dim: int = 64
     v_head_dim: int = 128

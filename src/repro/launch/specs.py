@@ -32,7 +32,7 @@ SHAPES = {
 # microbatch counts chosen so per-device activations fit 16 GB (v5e)
 TRAIN_MICROBATCHES = {
     "gemma3-27b": 8, "granite-34b": 16, "stablelm-3b": 4, "qwen3-32b": 16,
-    "deepseek-v2-236b": 16, "moonshot-v1-16b-a3b": 8,
+    "deepseek-v2-236b": 16, "moonlight-16b-a3b": 8,
     "recurrentgemma-2b": 4, "mamba2-1.3b": 4,
     "llama-3.2-vision-11b": 8, "musicgen-medium": 4,
 }

@@ -130,9 +130,11 @@ class LMDecodeSSM:
         logits, caches = M.forward_decode(
             self.params, self.cfg, state["token"][:, None],
             state["pos"][0], state["caches"])
-        logits = logits[:, 0].astype(jnp.float32)            # (K, V)
-        p_log = jax.nn.log_softmax(logits, axis=-1)
-        q_log = jax.nn.log_softmax(logits / dec.proposal_temperature, -1)
+        with jax.named_scope("ppf.lm.head"):
+            logits = logits[:, 0].astype(jnp.float32)        # (K, V)
+            p_log = jax.nn.log_softmax(logits, axis=-1)
+            q_log = jax.nn.log_softmax(logits / dec.proposal_temperature,
+                                       -1)
         tok = jax.random.categorical(key, q_log, axis=-1).astype(jnp.int32)
         pick = lambda lp: jnp.take_along_axis(      # noqa: E731
             lp, tok[:, None], -1)[:, 0]

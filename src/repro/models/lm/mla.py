@@ -12,6 +12,26 @@ Two apply paths:
   value computations run directly in the 512-dim latent space, so decode
   never materializes per-head K/V.  This is the TPU-native formulation of
   MLA serving (bandwidth-bound by the 576-wide cache stream).
+
+The layer, per token x (D) at position t, H heads (DeepSeek-V2/V3):
+
+    q_h        = W^Q_h x                      (no query low rank, V3's
+                 or W^QB_h RMSNorm(W^QA x)     ``q_lora_rank`` null)
+    [q^C_h ; q^R_h] = q_h                      (nope 128, rope 64)
+    c_t        = RMSNorm(W^DKV x)              (latent, kv_lora_rank 512)
+    k^R_t      = RoPE(W^KR x, t)               (one 64-wide key, all heads)
+    k^C_{t,h}  = W^UK_h c_t,   v_{t,h} = W^UV_h c_t
+    a_h(s)     = softmax_s((q^C_h · k^C_{s,h} + RoPE(q^R_h, t) · k^R_s)
+                           / sqrt(nope + rope)),  s <= t
+    out        = W^O [Σ_s a_h(s) v_{s,h}]_h
+
+The cache holds c_t and k^R_t: 576 numbers a token, whatever H is.
+Departures from the published checkpoints, each a fixed relabelling of
+random weights: the 64 rotary dims are rotated as two halves
+(``rotate_half``) in their stored order, where the Hugging Face DeepSeek
+code first de-interleaves them (a permutation of W^Q's and W^KR's
+rotary columns); RMSNorm gains are stored as ``1 + w`` (``rms_norm``);
+W^DKV and W^KR are one matrix ``wkv_a``.
 """
 from __future__ import annotations
 
@@ -34,12 +54,20 @@ def mla_params(key: Array, d_model: int, n_heads: int, cfg: MLAConfig,
     dqk = cfg.qk_nope_dim
     drope = cfg.qk_rope_dim
     dv = cfg.v_head_dim
-    return {
+    if qr:
         # query low-rank path: D → qr → H·(dqk + drope)
-        "wq_a": jax.random.normal(ks[0], (d_model, qr), dtype) * s,
-        "q_norm": jnp.zeros((qr,), dtype),
-        "wq_b": jax.random.normal(ks[1], (qr, n_heads * (dqk + drope)),
-                                  dtype) * qr ** -0.5,
+        query = {
+            "wq_a": jax.random.normal(ks[0], (d_model, qr), dtype) * s,
+            "q_norm": jnp.zeros((qr,), dtype),
+            "wq_b": jax.random.normal(ks[1], (qr, n_heads * (dqk + drope)),
+                                      dtype) * qr ** -0.5,
+        }
+    else:
+        # direct query projection: D → H·(dqk + drope)
+        query = {"wq": jax.random.normal(
+            ks[0], (d_model, n_heads * (dqk + drope)), dtype) * s}
+    return {
+        **query,
         # kv low-rank: D → (r latent + drope shared rotary key)
         "wkv_a": jax.random.normal(ks[2], (d_model, r + drope), dtype) * s,
         "kv_norm": jnp.zeros((r,), dtype),
@@ -66,7 +94,10 @@ def _queries(p: dict, x: Array, n_heads: int, cfg: MLAConfig,
              positions: Array, theta: float, eps: float):
     b, t, _ = x.shape
     dqk, drope = cfg.qk_nope_dim, cfg.qk_rope_dim
-    q = rms_norm(x @ p["wq_a"], p["q_norm"], eps) @ p["wq_b"]
+    if "wq" in p:
+        q = x @ p["wq"]
+    else:
+        q = rms_norm(x @ p["wq_a"], p["q_norm"], eps) @ p["wq_b"]
     q = q.reshape(b, t, n_heads, dqk + drope)
     q_nope, q_pe = q[..., :dqk], q[..., dqk:]
     q_pe = rope(q_pe, positions, theta)

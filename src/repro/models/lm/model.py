@@ -13,7 +13,14 @@ remainder (gemma3's 62 = 10·6 + 2) are unrolled around the scan.
 
 Three entry points: ``forward_train`` (hidden states — the loss is chunked
 over vocab in ``repro.train``), ``forward_prefill`` (hiddens + caches),
-``forward_decode`` (one token against caches).
+``forward_decode`` (one token against caches).  Expert layers train
+through the capacity dispatch (``moe.apply_moe`` / ``apply_moe_ep``) and
+serve (prefill and decode) through the dropless ``moe.apply_expert_share``.
+
+Named scopes put the latent attention (``ppf.lm.mla``), the expert layer
+(``ppf.lm.moe``) and the unembedding (``ppf.lm.head``) into each
+operation's ``op_name``, under the SIR step's own scopes when a decode
+step runs inside one (``repro.models.lm.decode_ssm``).
 """
 from __future__ import annotations
 
@@ -248,29 +255,8 @@ def _block_forward(bp: dict, x: Array, kind: str, ffn: str, cfg: ArchConfig,
         x = x + o @ bp["attn"]["wo"]
 
     elif kind == "M":
-        if mode == "decode":
-            c_new, pe_new = MLA.mla_compress(
-                bp["mla"], h, jnp.full((1,), pos, jnp.int32),
-                cfg.rope_theta, eps)
-            c_c = jax.lax.dynamic_update_slice_in_dim(cache["c"], c_new, pos, 1)
-            pe_c = jax.lax.dynamic_update_slice_in_dim(cache["pe"], pe_new,
-                                                       pos, 1)
-            o = MLA.mla_decode_absorbed(bp["mla"], h, cfg.n_heads, cfg.mla,
-                                        c_cache=c_c, pe_cache=pe_c, pos=pos,
-                                        theta=cfg.rope_theta, eps=eps)
-            new_cache = {"c": c_c, "pe": pe_c}
-        else:
-            o = MLA.mla_attention(bp["mla"], h, cfg.n_heads, cfg.mla,
-                                  positions=positions, theta=cfg.rope_theta,
-                                  eps=eps, chunk=cfg.attn_chunk,
-                                  unroll=cfg.scan_unroll,
-                                  scores_dtype=L.dtype_of(
-                                      cfg.attn_scores_dtype))
-            if mode == "prefill":
-                c_new, pe_new = MLA.mla_compress(bp["mla"], h, positions,
-                                                 cfg.rope_theta, eps)
-                new_cache = {"c": _pad_cache(c_new, cache["c"], axis=1),
-                             "pe": _pad_cache(pe_new, cache["pe"], axis=1)}
+        o, new_cache = _mla_block(bp["mla"], h, cfg, mode, cache, positions,
+                                  pos)
         x = x + o
 
     elif kind == "X":
@@ -333,16 +319,55 @@ def _block_forward(bp: dict, x: Array, kind: str, ffn: str, cfg: ArchConfig,
         x = x + L.apply_mlp(bp["mlp"], hf)
     elif ffn == "moe":
         hf = L.rms_norm(x, bp["ffn_norm"], eps)
-        moe_fn = (MOE.apply_moe_ep if cfg.moe.dispatch == "ep_shardmap"
-                  else MOE.apply_moe)
-        o, moe_aux = moe_fn(bp["moe"], hf, cfg.moe)
-        for k_, v_ in moe_aux.items():
-            aux[k_] = (aux[k_] + v_) if k_ in aux else v_
+        with jax.named_scope("ppf.lm.moe"):
+            if mode == "train":
+                moe_fn = (MOE.apply_moe_ep
+                          if cfg.moe.dispatch == "ep_shardmap"
+                          else MOE.apply_moe)
+                o, moe_aux = moe_fn(bp["moe"], hf, cfg.moe)
+                for k_, v_ in moe_aux.items():
+                    aux[k_] = (aux[k_] + v_) if k_ in aux else v_
+            else:
+                o = MOE.apply_expert_share(bp["moe"], hf, cfg.moe)
         x = x + o
 
     x = constrain(x, "act_sp" if (cfg.seq_parallel and mode != "decode")
                   else "act")
     return x, new_cache
+
+
+def _mla_block(p: dict, h: Array, cfg: ArchConfig, mode: str,
+               cache: Optional[dict], positions: Array, pos):
+    """Latent attention of one block under ``ppf.lm.mla``: its output and
+    the new cache (``cache`` itself in training)."""
+    eps = cfg.norm_eps
+    new_cache = cache
+    with jax.named_scope("ppf.lm.mla"):
+        if mode == "decode":
+            c_new, pe_new = MLA.mla_compress(
+                p, h, jnp.full((1,), pos, jnp.int32),
+                cfg.rope_theta, eps)
+            c_c = jax.lax.dynamic_update_slice_in_dim(cache["c"], c_new,
+                                                      pos, 1)
+            pe_c = jax.lax.dynamic_update_slice_in_dim(cache["pe"], pe_new,
+                                                       pos, 1)
+            o = MLA.mla_decode_absorbed(p, h, cfg.n_heads, cfg.mla,
+                                        c_cache=c_c, pe_cache=pe_c, pos=pos,
+                                        theta=cfg.rope_theta, eps=eps)
+            new_cache = {"c": c_c, "pe": pe_c}
+        else:
+            o = MLA.mla_attention(p, h, cfg.n_heads, cfg.mla,
+                                  positions=positions, theta=cfg.rope_theta,
+                                  eps=eps, chunk=cfg.attn_chunk,
+                                  unroll=cfg.scan_unroll,
+                                  scores_dtype=L.dtype_of(
+                                      cfg.attn_scores_dtype))
+            if mode == "prefill":
+                c_new, pe_new = MLA.mla_compress(p, h, positions,
+                                                 cfg.rope_theta, eps)
+                new_cache = {"c": _pad_cache(c_new, cache["c"], axis=1),
+                             "pe": _pad_cache(pe_new, cache["pe"], axis=1)}
+    return o, new_cache
 
 
 def _pad_cache(fresh: Array, template: Array, axis: int = 2) -> Array:
@@ -408,7 +433,7 @@ def _run_blocks(params: dict, cfg: ArchConfig, x: Array, mode: str,
     if plan.n_groups:
         cache_stack = caches.get("blocks") if caches else None
         acc0: dict[str, Any] = {}
-        if cfg.moe:
+        if cfg.moe and mode == "train":
             acc0 = {"moe_aux_loss": jnp.zeros((), jnp.float32),
                     "moe_drop_frac": jnp.zeros((), jnp.float32),
                     "moe_max_load": jnp.zeros((), jnp.int32)}
@@ -509,18 +534,23 @@ def forward_decode(params: dict, cfg: ArchConfig, tokens: Array, pos,
     positions = jnp.full((1,), pos, jnp.int32)
     x, new_caches, _ = _run_blocks(params, cfg, x, "decode", caches,
                                    positions, pos, None)
-    x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = unembed(params, cfg, x)
+    with jax.named_scope("ppf.lm.head"):
+        x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
+        logits = unembed(params, cfg, x)
     return logits, new_caches
 
 
 def cast_params(params: dict, cfg: ArchConfig) -> dict:
-    """Cast float params to compute dtype (bf16 matmuls, f32 master copy)."""
+    """Cast float params to compute dtype (bf16 matmuls, f32 master copy);
+    the experts' selection bias keeps its own dtype, since the router
+    chooses in float32 (``moe.route``)."""
     ct = L.dtype_of(cfg.compute_dtype)
 
-    def cast(x):
+    def cast(path, x):
+        if getattr(path[-1], "key", None) == "router_bias":
+            return x
         if isinstance(x, jax.Array) and jnp.issubdtype(x.dtype, jnp.floating):
             return x.astype(ct)
         return x
 
-    return jax.tree_util.tree_map(cast, params)
+    return jax.tree_util.tree_map_with_path(cast, params)

@@ -65,6 +65,7 @@ class SMCDecodeResult(NamedTuple):
     resampled: Array     # (steps, B) ESS-trigger trace
     ancestors: Array     # (steps, B, K) recorded ancestor indices
     emissions: Array     # (steps, B, K) pre-gather token draws
+    logp: Array          # (B, K) each row's summed target log p
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "dcfg", "t0", "reward"))
@@ -128,6 +129,7 @@ def smc_decode(params, cfg: ArchConfig, prompt: Array,
             [jnp.zeros((1, b), outs.resampled.dtype), outs.resampled], 0),
         ancestors=jnp.concatenate([ident, outs.ancestors], 0),
         emissions=jnp.concatenate([first[None], outs.diag["emission"]], 0),
+        logp=carry.ensemble.state["logp"],
     )
 
 

@@ -18,7 +18,7 @@ from repro.train import TrainConfig, make_train_step
 # configs dominate this file's wall-clock (measured with --durations:
 # together they were ~60% of it), so they run in the slow lane.  Every
 # architecture family keeps a tier-1 representative: attention/GQA →
-# stablelm-3b, qwen3-32b, granite-34b; MLA + MoE → moonshot-v1-16b-a3b;
+# stablelm-3b, qwen3-32b, granite-34b; MLA + MoE → moonlight-16b-a3b;
 # RG-LRU → recurrentgemma-2b; SSM → mamba2-1.3b; vision cross-attn →
 # llama-3.2-vision-11b; multi-codebook → musicgen-medium.
 HEAVY_ARCHS = {"gemma3-27b", "deepseek-v2-236b"}
@@ -102,7 +102,7 @@ def test_layer_plan_counts():
 
 
 def test_moe_load_diagnostics():
-    cfg = get_config("moonshot-v1-16b-a3b", smoke=True)
+    cfg = get_config("moonlight-16b-a3b", smoke=True)
     params = M.init_params(KEY, cfg)
     batch = _batch_for(cfg, 2, 64)
     _, aux = M.forward_train(params, cfg, batch["tokens"])

@@ -83,11 +83,11 @@ def test_param_spec_divisibility_on_production_mesh(arch):
 
 def test_model_flops_moe_counts_active_only():
     dense = get_config("stablelm-3b")
-    moe = get_config("moonshot-v1-16b-a3b")
+    moe = get_config("moonlight-16b-a3b")
     info = {"batch": 8, "seq": 128, "kind": "train"}
     f_dense = RL.model_flops(dense, info)
     a_moe = RL.active_params(moe)
-    # moonshot: 16B total, ~3B active
+    # moonlight: 16B total, ~3B active
     import jax as _jax
     params = _jax.eval_shape(lambda: M.init_params(_jax.random.key(0), moe))
     total = sum(x.size for x in _jax.tree_util.tree_leaves(params))

@@ -296,3 +296,47 @@ def test_scopes_change_nothing_but_op_names(backend, monkeypatch):
     plain = _sir_step_program(backend).compile().as_text()
     assert "ppf." not in plain
     assert _program_body(plain) == scoped
+
+
+def _decode_step_program():
+    """The SMC decode step of the MLA + MoE smoke model, one prompt of K
+    rows, as ``smc_decode`` scans it, jitted afresh."""
+    from repro.configs import get_config
+    from repro.models.lm import decode_ssm
+    from repro.models.lm import model as M
+    cfg = get_config("moonlight-16b-a3b", smoke=True)
+    params = M.init_params(jax.random.key(0), cfg)
+    dcfg = decode_ssm.SMCDecodeConfig(n_particles=4, steps=4)
+    model = decode_ssm.LMDecodeSSM(params=params, cfg=cfg, decode=dcfg,
+                                   prompt_len=6)
+    carry = decode_ssm.decode_carry(model, jax.random.key(1),
+                                    jnp.arange(6) % cfg.vocab_size)[0]
+    carry = jax.tree_util.tree_map(lambda x: x[None], carry)
+    step = filters.make_bank_step(model, dcfg.sir())
+    return jax.jit(step).lower(carry, (jnp.ones((1,), jnp.float32),
+                                       jnp.ones((1,), bool)))
+
+
+def test_decode_step_names_the_model_parts(monkeypatch):
+    """The decode step carries the latent attention, the expert layer and
+    the head under ``ppf.lm.*`` inside ``ppf.advance``, and compiles to the
+    same program, instruction for instruction, without the scopes."""
+    compiled = _decode_step_program().compile().as_text()
+    found = set(SCOPE.findall(compiled))
+    assert {"ppf.advance", "ppf.lm.mla", "ppf.lm.moe", "ppf.lm.head",
+            "ppf.gather"} <= found, found
+    assert re.search(r"ppf\.advance\)?/[^\"]*ppf\.lm\.moe", compiled)
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    plain = _decode_step_program().compile().as_text()
+    assert "ppf." not in plain
+    assert _renamed(_program_body(plain)) == _renamed(_program_body(compiled))
+
+
+def _renamed(body: str) -> str:
+    """HLO text with its names numbered in order of first appearance: the
+    scopes may shift the suffixes XLA gives an instruction's name."""
+    names: dict = {}
+    return re.sub(r"%[\w.\-]+",
+                  lambda m: names.setdefault(m.group(0), f"%v{len(names)}"),
+                  body)
