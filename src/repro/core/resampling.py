@@ -17,6 +17,16 @@ has two output forms:
 
 ``counts_to_ancestors`` / ``ancestors_to_counts`` convert between the two
 losslessly (up to offspring ordering, which is exchangeable).
+
+The systematic comb is search-free: its points ``(k + u) / n_out`` are
+regular, so the number of them below each CDF entry has a closed form
+(``systematic_comb_counts``), and particle ``j``'s offspring fill the
+slots between two neighbouring entries.  The CDF goes through
+``lax.cummax`` first: a float32 ``cumsum`` of non-negative weights can
+dip by rounding at large ``n``, where a search has no single answer,
+and the running max keeps every count non-negative.  Stratified,
+multinomial and residual points are not a regular comb and keep
+``searchsorted`` (DESIGN.md §2.3).
 """
 from __future__ import annotations
 
@@ -35,12 +45,21 @@ Array = jax.Array
 def counts_to_ancestors(counts: Array, n_out: int) -> Array:
     """Expand multiplicities to ancestor indices.
 
-    ``jnp.repeat`` with a static total length keeps SPMD shapes fixed; if
-    ``sum(counts) < n_out`` the tail is padded with the last valid index
-    (callers track logical size separately).
+    Particle ``j``'s offspring occupy slots ``[starts_j, starts_j +
+    counts_j)``: one sorted scatter-max writes ``j`` at ``starts_j`` and
+    a running max fills the rest of each run.  Zero-count particles share
+    their start with the next particle that has offspring, which the max
+    lets win.  The static total length keeps SPMD shapes fixed; if
+    ``sum(counts) < n_out`` the tail is padded with ``n_in - 1`` (the
+    trailing particles write their index at the total, as
+    ``jnp.repeat`` pads), and callers track the logical size separately.
     """
     n_in = counts.shape[0]
-    return jnp.repeat(jnp.arange(n_in, dtype=jnp.int32), counts, total_repeat_length=n_out)
+    starts = jnp.cumsum(counts) - counts
+    slots = jnp.zeros((n_out,), jnp.int32).at[starts].max(
+        jnp.arange(n_in, dtype=jnp.int32), indices_are_sorted=True,
+        mode="drop")
+    return jax.lax.cummax(slots)
 
 
 def ancestors_to_counts(ancestors: Array, n_in: int) -> Array:
@@ -58,10 +77,14 @@ def _comb_counts(weights: Array, u: Array, n_out: Array | int, capacity: int) ->
     ``u`` is either a scalar (systematic) or ``(capacity,)`` (stratified)
     uniform in [0,1).  ``n_out`` may be a *traced* scalar ≤ ``capacity`` —
     this is what lets RPA allocate a data-dependent number of offspring per
-    shard while every shape stays static (DESIGN.md §2.1).
+    shard while every shape stays static (DESIGN.md §2.1).  A scalar ``u``
+    takes the closed form (``systematic_comb_counts``); per-point offsets
+    search.
     """
     w = weights / jnp.maximum(jnp.sum(weights), 1e-38)
     cdf = jnp.cumsum(w)
+    if jnp.ndim(u) == 0:
+        return systematic_comb_counts(cdf, u, n_out, capacity)
     n_out_f = jnp.asarray(n_out, jnp.float32)
     pts = (jnp.arange(capacity, dtype=jnp.float32) + u) / jnp.maximum(n_out_f, 1.0)
     valid = jnp.arange(capacity) < n_out
@@ -74,6 +97,37 @@ def _comb_counts(weights: Array, u: Array, n_out: Array | int, capacity: int) ->
         jnp.where(valid, 1, 0)
     )
     return counts
+
+
+def systematic_comb_counts(cdf: Array, u: Array, n_out: Array | int,
+                           capacity: int) -> Array:
+    """Offspring counts of the systematic comb over ``cdf``, with no search.
+
+    The comb's points ``(k + u) / n_out`` are regular, so the number of
+    them below ``cdf_j``, ``c_j``, is ``ceil(n_out·cdf_j − u)`` up to
+    float32 rounding, and elementwise comparisons against the points
+    themselves, evaluated as the stratified search evaluates its points,
+    correct it; particle ``j`` has ``c_j − c_{j−1}`` offspring.  Each
+    round moves an entry by one; the rounds grow with float32's spacing
+    of integers above 2^22.  The count runs on the CDF's running max, so
+    it is non-negative and sums to ``n_out`` even where ``cdf`` dips by
+    rounding; where it does not dip, the counts are those of a
+    ``searchsorted`` of every point, the last particle taking every
+    point past the CDF's end as the search's clip does.
+    """
+    cdf = jax.lax.cummax(cdf)
+    n_out_f = jnp.asarray(n_out, jnp.float32)
+    n_out_i = jnp.asarray(n_out, jnp.int32)
+    scale = jnp.maximum(n_out_f, 1.0)
+
+    def below(k):
+        return (k.astype(jnp.float32) + u) / scale < cdf
+
+    c = jnp.clip(jnp.ceil(cdf * scale - u), 0.0, n_out_f).astype(jnp.int32)
+    for _ in range(1 + capacity // 2 ** 22):
+        c = jnp.where((c > 0) & ~below(c - 1), c - 1, c)
+        c = jnp.where((c < n_out_i) & below(c), c + 1, c)
+    return jnp.diff(c.at[-1].set(n_out_i), prepend=0)
 
 
 def systematic_counts(key: Array, log_weights: Array, n_out, capacity: int | None = None) -> Array:

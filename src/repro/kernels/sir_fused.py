@@ -14,9 +14,9 @@ phase that normalizes once and shares the result (DESIGN.md §13):
     w   = softmax(lw')           (single max/exp/sum)
     estimate = Σ w·x             (f32 accumulation, state may be bf16)
     ESS, log Z, resample decision
-    ancestors — systematic comb via direct searchsorted (no counts
-    round-trip), or the collective-free Metropolis/rejection chains
-    (repro.core.resampling) which need no CDF at all
+    ancestors — the systematic comb in closed form over this CDF, or the
+    collective-free Metropolis/rejection chains, which need no CDF at
+    all (both in repro.core.resampling)
 
 Three backends, same contract as the rest of the kernel layer:
 
@@ -97,9 +97,9 @@ def fused_weight_step_ref(log_weights: Array, log_lik: Array, state: Any,
     computed once and shared by the estimate, ESS, log-Z, and the comb
     CDF, where the composed ops each re-derive it — every shared
     quantity agrees with the composed path to ≤ 1 ulp, and the
-    systematic ancestors come from a direct searchsorted over the
-    *singly*-normalized CDF instead of the counts round-trip (drift
-    bound measured and pinned by tests/test_ssm_parity.py; DESIGN.md
+    systematic ancestors come from the closed-form comb over the
+    *singly*-normalized CDF, not the composed path's re-normalized one
+    (drift bound measured and pinned by tests/test_ssm_parity.py; DESIGN.md
     §13.3).  The estimate keeps ``weighted_mean``'s multiply+sum form so
     bank slots stay vmap-bitwise-stable (DESIGN.md §11.2).
     """
@@ -137,10 +137,8 @@ def _ref_ancestors(w: Array, lw: Array, key: Array, resampler: str) -> Array:
     n = w.shape[0]
     if resampler == "systematic":
         u = jax.random.uniform(key, ())
-        cdf = jnp.cumsum(w)
-        pts = (jnp.arange(n, dtype=jnp.float32) + u) / n
-        anc = jnp.searchsorted(cdf, pts, side="right")
-        return jnp.clip(anc, 0, n - 1).astype(jnp.int32)
+        counts = resampling.systematic_comb_counts(jnp.cumsum(w), u, n, n)
+        return resampling.counts_to_ancestors(counts, n)
     if resampler in resampling.COLLECTIVE_FREE:
         iters = (resampling.METROPOLIS_ITERS if resampler == "metropolis"
                  else resampling.REJECTION_TRIES)
